@@ -27,7 +27,10 @@ import os
 import re
 from typing import List, Tuple
 
-from repro.core.roofline import HBM_BW, PEAK_FLOPS_BF16
+from repro.core.roofline import V5E, peaks_for
+
+HBM_BW = peaks_for(V5E).hbm_bw
+PEAK_FLOPS_BF16 = peaks_for(V5E).flops_bf16
 
 ART = os.path.normpath(
     os.path.join(os.path.dirname(__file__), "..", "artifacts", "dryrun",
@@ -47,7 +50,7 @@ def measure_depth2_bytes(arch: str) -> float:
     mesh = make_production_mesh(multi_pod=False)
     fn, args, _, meta = D.build_cell(arch, "train_4k", mesh)
     rules = meta.pop("_rules")
-    with mesh, use_rules(rules):
+    with mesh, use_rules(rules, mesh):
         co = fn.lower(*args).compile()
     model = hlo_cost.HloCostModel(co.as_text(), CHIPS)
     total = {"d2": 0.0}

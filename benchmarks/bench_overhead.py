@@ -102,13 +102,13 @@ def run() -> List[Tuple[str, float, str]]:
     b = jax.random.normal(jax.random.key(1), (256, 256), jnp.float32)
     cases.append((
         "gemm_v00",
-        lambda: ops.matmul(a, b, variant="v00"),
+        lambda: ops.matmul(a, b, variant="v00", interpret=True),
         gemm_v00_spec(256, 256, 256),
         None,
     ))
     cases.append((
         "gemm_v01",
-        lambda: ops.matmul(a, b, variant="v01"),
+        lambda: ops.matmul(a, b, variant="v01", interpret=True),
         gemm_v01_spec(256, 256, 256),
         None,
     ))
@@ -120,7 +120,7 @@ def run() -> List[Tuple[str, float, str]]:
     xg = jax.random.normal(key, (16384 // 16, 16), jnp.float32)
     cases.append((
         "spmv_csr",
-        lambda: ops.spmv(vals, xg),
+        lambda: ops.spmv(vals, xg, interpret=True),
         spmv_csr_spec(16384, 4096),
         {"col_indices": colidx},
     ))
@@ -130,7 +130,7 @@ def run() -> List[Tuple[str, float, str]]:
     tu = jax.random.normal(key, (512, 8, 32), jnp.float32)
     cases.append((
         "pasta_ttm",
-        lambda: ops.ttm(tv, tu, use_scratch=True),
+        lambda: ops.ttm(tv, tu, use_scratch=True, interpret=True),
         ttm_scratch_spec(512, 8, 32),
         None,
     ))
@@ -140,7 +140,7 @@ def run() -> List[Tuple[str, float, str]]:
     am = jax.random.normal(key, (512, 512), jnp.float32)
     cases.append((
         "gramschm_k3",
-        lambda: ops.gramschm_k3(q, am, k=3),
+        lambda: ops.gramschm_k3(q, am, k=3, interpret=True),
         k3_naive_block_spec(512, 512, 512, k=3),
         None,
     ))
@@ -149,7 +149,7 @@ def run() -> List[Tuple[str, float, str]]:
     cells = jax.random.randint(key, (65536,), 0, 2048)
     cases.append((
         "gpumd_cells",
-        lambda: ops.histogram(cells, 2048),
+        lambda: ops.histogram(cells, 2048, interpret=True),
         hist_opt_spec(65536, 2048),
         None,
     ))

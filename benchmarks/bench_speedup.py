@@ -53,8 +53,8 @@ def run() -> List[Tuple[str, float, str]]:
     hm1 = analyze(gemm_v01_spec(1024, 1024, 1024), S)
     a = jax.random.normal(jax.random.key(0), (256, 256), jnp.float32)
     b = jax.random.normal(jax.random.key(1), (256, 256), jnp.float32)
-    w0 = _time(lambda: ops.matmul(a, b, variant="v00"))
-    w1 = _time(lambda: ops.matmul(a, b, variant="v01"))
+    w0 = _time(lambda: ops.matmul(a, b, variant="v00", interpret=True))
+    w1 = _time(lambda: ops.matmul(a, b, variant="v01", interpret=True))
     rows.append(("gemm_v00->v01",
                  hm0.sector_transactions() / 32,
                  hm1.sector_transactions() / 256, 721.79, w0, w1))
@@ -63,7 +63,7 @@ def run() -> List[Tuple[str, float, str]]:
     # gain was capped by a 99.2% L1 hit rate absorbing B re-fetches; TPU
     # has no data cache, so explicit tiling saves the full traffic)
     hm2 = analyze(gemm_v02_spec(1024, 1024, 1024), GridSampler(None))
-    w2 = _time(lambda: ops.matmul(a, b, variant="v02", bm=64, bn=64, bk=64))
+    w2 = _time(lambda: ops.matmul(a, b, variant="v02", bm=64, bn=64, bk=64, interpret=True))
     rows.append(("gemm_v01->v02",
                  hm1.sector_transactions() / 256,
                  hm2.sector_transactions() / 1024, 26.07, w1, w2))
@@ -81,8 +81,8 @@ def run() -> List[Tuple[str, float, str]]:
     # PASTA scratch -> registers (paper: +163.56%)
     tv = jax.random.normal(jax.random.key(2), (512, 8), jnp.float32)
     tu = jax.random.normal(jax.random.key(3), (512, 8, 32), jnp.float32)
-    ws = _time(lambda: ops.ttm(tv, tu, use_scratch=True))
-    wf = _time(lambda: ops.ttm(tv, tu, use_scratch=False))
+    ws = _time(lambda: ops.ttm(tv, tu, use_scratch=True, interpret=True))
+    wf = _time(lambda: ops.ttm(tv, tu, use_scratch=False, interpret=True))
     # scratch round-trip bytes modeled as the saved traffic
     hm_ts = analyze(ttm_scratch_spec(512, 8, 32), S)
     hm_tf = analyze(ttm_fused_spec(512, 8, 32), S)
@@ -99,8 +99,8 @@ def run() -> List[Tuple[str, float, str]]:
     hm_g1 = analyze(k3_opt_spec(512, 512, 512, k=3), GridSampler(None))
     q = jax.random.normal(jax.random.key(4), (512, 512), jnp.float32)
     am = jax.random.normal(jax.random.key(5), (512, 512), jnp.float32)
-    wg0 = _time(lambda: ops.gramschm_k3(q, am, k=3, naive=True))
-    wg1 = _time(lambda: ops.gramschm_k3(q.T, am, k=3, naive=False))
+    wg0 = _time(lambda: ops.gramschm_k3(q, am, k=3, naive=True, interpret=True))
+    wg1 = _time(lambda: ops.gramschm_k3(q.T, am, k=3, naive=False, interpret=True))
     rows.append(("gramschm_k3", hm_g0.sector_transactions(),
                  hm_g1.sector_transactions(), 23.18, wg0, wg1))
 
@@ -111,8 +111,8 @@ def run() -> List[Tuple[str, float, str]]:
                     dynamic_context={"cells": cells_np})
     hm_h1 = analyze(hist_opt2_spec(65536, 2048), GridSampler(None))
     cells = jnp.asarray(cells_np, jnp.int32)
-    wh0 = _time(lambda: ops.histogram(cells, 2048, naive=True))
-    wh1 = _time(lambda: ops.histogram(cells, 2048, naive=False))
+    wh0 = _time(lambda: ops.histogram(cells, 2048, naive=True, interpret=True))
+    wh1 = _time(lambda: ops.histogram(cells, 2048, naive=False, interpret=True))
     rows.append(("gpumd_cells", hm_h0, hm_h1, None, wh0, wh1))
 
     for name, before, after, paper, wb, wa in rows:

@@ -27,9 +27,11 @@ def main() -> None:
 
     # ONE warm shard pool for the whole suite: the collect bench pays
     # the spawn+import cost once (and records it), the tune bench then
-    # profiles its candidates on the same warm workers
+    # profiles its candidates on the same warm workers.  The workers'
+    # JAX is held to the CPU, so this process alone may hold a chip.
     collector = ShardedCollector(bench_overhead.effective_workers(4))
     rows = []
+    failed = []
     try:
         for name, runner in (
             ("patterns (paper Table I)", bench_patterns.run),
@@ -55,12 +57,15 @@ def main() -> None:
             except Exception as e:  # noqa: BLE001 — keep the suite going
                 print(f"# FAILED: {e!r}")
                 rows.append((name, 0.0, f"FAILED {e!r}"))
+                failed.append(name)
     finally:
         collector.close()
 
     print("\n===== summary: name,us_per_call,derived =====")
     for name, us, derived in rows:
         print(f"{name},{us:.1f},{derived}")
+    if failed:
+        sys.exit(f"{len(failed)} benchmark section(s) failed: {', '.join(failed)}")
 
 
 if __name__ == "__main__":

@@ -1346,6 +1346,9 @@ def _cmd_check(args: argparse.Namespace) -> int:
 
 def main(argv: Optional[List[str]] = None) -> int:
     """Entry point for the ``cuthermo`` console script."""
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     parser = _build_parser()
     args = parser.parse_args(argv)
     if not getattr(args, "func", None):

@@ -48,6 +48,8 @@ serial (and ``ShardedCollector.analyze`` warns).
 from __future__ import annotations
 
 import dataclasses
+import os
+import sys
 import threading
 import time
 from typing import Callable, Dict, Iterable, List, Optional, Sequence, Tuple
@@ -567,6 +569,19 @@ def collect_shard(
     return buf, info
 
 
+def _hold_jax_to_cpu() -> None:
+    """Pool initializer: workers walk grids with NumPy, never a device.
+
+    The parent may hold the accelerator (one process per chip), so a
+    worker's JAX is pinned to the CPU before it can start a backend, and
+    so is any process the worker starts.
+    """
+    os.environ["JAX_PLATFORMS"] = "cpu"
+    jax = sys.modules.get("jax")
+    if jax is not None:
+        jax.config.update("jax_platforms", "cpu")
+
+
 def _warm_worker(_: int) -> bool:
     """Pool warmup: pay the kernel-registry import once per worker."""
     from repro import kernels  # noqa: F401  (import is the work)
@@ -820,6 +835,7 @@ class ShardedCollector:
                 self._pool = concurrent.futures.ProcessPoolExecutor(
                     max_workers=self.workers,
                     mp_context=multiprocessing.get_context(self.start_method),
+                    initializer=_hold_jax_to_cpu,
                 )
             return self._pool
 

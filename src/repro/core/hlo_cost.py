@@ -22,6 +22,8 @@ import re
 from collections import defaultdict
 from typing import Dict, List, Optional, Tuple
 
+import numpy as np
+
 _DTYPE_BYTES = {
     "pred": 1, "s2": 1, "u2": 1, "s4": 1, "u4": 1, "s8": 1, "u8": 1,
     "s16": 2, "u16": 2, "bf16": 2, "f16": 2,
@@ -45,6 +47,30 @@ _CONTRACT_RE = re.compile(r"lhs_contracting_dims=\{([0-9,]*)\}")
 _CONST_RE = re.compile(r"constant\((\d+)\)")
 _REPLICA_RE = re.compile(r"replica_groups=\{(.*?)\}\}?")
 _REPLICA_IOTA_RE = re.compile(r"replica_groups=\[(\d+),(\d+)\]")
+_DIM_LABELS_RE = re.compile(r"dim_labels=(\w+)_(\w+)->(\w+)")
+_WINDOW_RE = re.compile(r"window=\{([^}]*)\}")
+
+
+def _dims(shape_text: str) -> Optional[List[int]]:
+    """Dimensions of the first array shape in ``shape_text``."""
+    m = _SHAPE_RE.search(shape_text)
+    return [int(d) for d in m.group(2).split(",") if d] if m else None
+
+
+def _window_attrs(line: str) -> Tuple[List, ...]:
+    """Per-spatial-dim (size, stride, (pad_lo, pad_hi), lhs_dilate,
+    rhs_dilate) lists of a convolution's ``window={...}``."""
+    m = _WINDOW_RE.search(line)
+    attrs = dict(kv.split("=", 1) for kv in m.group(1).split()) if m else {}
+
+    def ints(key: str) -> List[int]:
+        return [int(v) for v in attrs[key].split("x")] if key in attrs else []
+
+    pads = [
+        tuple(int(v) for v in p.split("_"))
+        for p in attrs.get("pad", "").split("x") if p
+    ]
+    return ints("size"), ints("stride"), pads, ints("lhs_dilate"), ints("rhs_dilate")
 
 
 def _shape_elems_bytes(shape_text: str) -> Tuple[int, int]:
@@ -279,8 +305,8 @@ class HloCostModel:
                         if di < len(dims):
                             contract *= dims[di]
             c.flops += 2.0 * out_elems * contract
-        elif ins.op in ("convolution",):
-            c.flops += 2.0 * out_elems  # lower bound (rare here)
+        elif ins.op == "convolution":
+            c.flops += self._conv_flops(ins, out_elems)
         elif ins.op not in _SKIP_BYTES:
             # elementwise/reduce/etc: ~1 flop per output element
             c.flops += float(out_elems)
@@ -312,6 +338,44 @@ class HloCostModel:
                 c.by_collective[coll] += wire
                 break
         return c
+
+    def _conv_flops(self, ins: Instr, out_elems: int) -> float:
+        """2 x multiply-adds of a convolution, padding taps excluded.
+
+        The TPU backend writes most dots as convolutions
+        (``dim_labels=0bf_oi0->0bf``): each output element contracts the
+        kernel's input features times the window taps that land on real
+        (not padded, not dilation-hole) input, as HloCostAnalysis counts.
+        """
+        labels = _DIM_LABELS_RE.search(ins.line)
+        lhs_dims = _dims(self.shapes.get(ins.operands[0], "")) if ins.operands else None
+        rhs_dims = (
+            _dims(self.shapes.get(ins.operands[1], ""))
+            if len(ins.operands) > 1 else None
+        )
+        out_dims = _dims(ins.shape)
+        if not (labels and lhs_dims and rhs_dims and out_dims):
+            return 2.0 * out_elems  # unparseable: count the outputs only
+        lhs_l, rhs_l, out_l = labels.groups()
+        window = _window_attrs(ins.line)
+        taps = 1
+        out_spatial = 1
+        for d, ch in enumerate(c for c in out_l if c.isdigit()):
+            n_in = lhs_dims[lhs_l.index(ch)]
+            n_out = out_dims[out_l.index(ch)]
+            out_spatial *= n_out
+            size, stride, (lo, _hi), lhs_dil, rhs_dil = (
+                w[d] if d < len(w) else dflt
+                for w, dflt in zip(window, (1, 1, (0, 0), 1, 1))
+            )
+            o = np.arange(n_out)[:, None]
+            k = np.arange(size)[None, :]
+            pos = o * stride + k * rhs_dil - lo
+            taps *= int(np.count_nonzero(
+                (pos >= 0) & (pos <= (n_in - 1) * lhs_dil) & (pos % lhs_dil == 0)
+            ))
+        in_features = rhs_dims[rhs_l.index("i")]
+        return 2.0 * (out_elems // max(1, out_spatial)) * in_features * taps
 
     # -- fusion byte model ---------------------------------------------------
 

@@ -54,9 +54,11 @@ COLLECTIVE_OPS = (
 
 # e.g.  f32[128,1024]{1,0}  or  bf16[2,16,16]
 _SHAPE_RE = re.compile(r"([a-z0-9]+)\[([0-9,]*)\]")
+# a tuple shape's leaves carry TPU tiled layouts with their own parens,
+# e.g.  (f32[8]{0:T(256)}, bf16[4,128]{1,0:T(8,128)(2,1)})
 _INSTR_RE = re.compile(
     r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=\s*"
-    r"((?:\([^)]*\))|(?:[a-z0-9]+\[[0-9,]*\][^ ]*))\s+"
+    r"((?:\((?:[^()]|\([^()]*\))*\))|(?:[a-z0-9]+\[[0-9,]*\][^ ]*))\s+"
     r"([a-z0-9\-]+)\(",
 )
 _REPLICA_GROUPS_RE = re.compile(r"replica_groups=\{(.*?)\}\s*(?:,|$)")

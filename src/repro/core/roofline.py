@@ -1,4 +1,4 @@
-"""Three-term roofline model for TPU v5e (the §Roofline deliverable).
+"""Three-term roofline model per chip kind (the §Roofline deliverable).
 
     compute term    = HLO_FLOPs_per_device   / peak_FLOP/s
     memory term     = HLO_bytes_per_device   / HBM_bw
@@ -16,15 +16,43 @@ for MFU.
 from __future__ import annotations
 
 import dataclasses
-from typing import Dict, Optional
+from typing import Dict
 
-from .hlo_thermo import HloHeat, analyze_hlo, cost_analysis_dict
 
-# TPU v5e hardware constants (per chip)
-PEAK_FLOPS_BF16 = 197e12  # FLOP/s
-HBM_BW = 819e9  # B/s
-ICI_BW_PER_LINK = 50e9  # B/s per link (~)
-HBM_PER_CHIP = 16 * 1024**3  # 16 GiB
+
+@dataclasses.dataclass(frozen=True)
+class ChipPeaks:
+    """Published per-chip peaks of one accelerator kind."""
+
+    flops_bf16: float  # FLOP/s
+    hbm_bw: float  # B/s
+    hbm_bytes: int
+    ici_bw_per_link: float  # B/s
+
+
+# Keyed by ``jax.Device.device_kind``.  Source: Google Cloud documentation,
+# "TPU v5e": 197 TFLOP/s bf16, 16 GB of HBM at 819 GB/s, 1,600 Gbit/s of
+# inter-chip interconnect per chip (four links).
+PEAKS: Dict[str, ChipPeaks] = {
+    "TPU v5 lite": ChipPeaks(
+        flops_bf16=197e12, hbm_bw=819e9, hbm_bytes=16 * 1000**3,
+        ici_bw_per_link=50e9,
+    ),
+}
+
+# the chip the dry-run models on the CPU (``jax.devices()`` there is not it)
+V5E = "TPU v5 lite"
+
+
+def peaks_for(device_kind: str) -> ChipPeaks:
+    """The peaks of ``device_kind``; an unknown kind is an error."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(
+            f"no published peaks for device kind {device_kind!r}; "
+            f"known: {sorted(PEAKS)}"
+        ) from None
 
 
 @dataclasses.dataclass(frozen=True)
@@ -36,6 +64,7 @@ class RooflineTerms:
     """
 
     name: str
+    device_kind: str  # a key of PEAKS
     chips: int
     hlo_flops: float
     hlo_bytes: float
@@ -43,16 +72,20 @@ class RooflineTerms:
     model_flops: float = 0.0  # 6*N*D useful-work model (global)
 
     @property
+    def peaks(self) -> ChipPeaks:
+        return peaks_for(self.device_kind)
+
+    @property
     def compute_s(self) -> float:
-        return self.hlo_flops / PEAK_FLOPS_BF16
+        return self.hlo_flops / self.peaks.flops_bf16
 
     @property
     def memory_s(self) -> float:
-        return self.hlo_bytes / HBM_BW
+        return self.hlo_bytes / self.peaks.hbm_bw
 
     @property
     def collective_s(self) -> float:
-        return self.collective_bytes / ICI_BW_PER_LINK
+        return self.collective_bytes / self.peaks.ici_bw_per_link
 
     @property
     def bound(self) -> str:
@@ -81,7 +114,7 @@ class RooflineTerms:
         """Model-FLOPs utilization at the roofline step time."""
         if self.step_s <= 0:
             return 0.0
-        return self.model_flops / (self.step_s * self.chips * PEAK_FLOPS_BF16)
+        return self.model_flops / (self.step_s * self.chips * self.peaks.flops_bf16)
 
     @property
     def roofline_fraction(self) -> float:
@@ -115,75 +148,3 @@ class RooflineTerms:
             f"useful-FLOP {100*self.useful_flop_fraction:.0f}%, "
             f"MFU@roofline {100*self.mfu:.1f}%"
         )
-
-
-def from_compiled(
-    name: str,
-    compiled,
-    chips: int,
-    model_flops: float = 0.0,
-    hlo_text: Optional[str] = None,
-) -> RooflineTerms:
-    """Build terms from a compiled module (+ optional pre-fetched HLO text)."""
-    ca = cost_analysis_dict(compiled)
-    text = hlo_text if hlo_text is not None else compiled.as_text()
-    heat = analyze_hlo(text)
-    return RooflineTerms(
-        name=name,
-        chips=chips,
-        hlo_flops=ca.get("flops", 0.0),
-        hlo_bytes=ca.get("bytes accessed", 0.0),
-        collective_bytes=heat.collective_bytes,
-        model_flops=model_flops,
-    )
-
-
-def from_heatmap(
-    name: str,
-    hm,
-    chips: int = 1,
-    flops: float = 0.0,
-    model_flops: float = 0.0,
-    collective_bytes: float = 0.0,
-) -> RooflineTerms:
-    """Build terms from a kernel heat map's modeled transaction counts.
-
-    The memory term comes straight from the array-backed heat map: every
-    modeled sector transaction moves one native tile (``sector_bytes``)
-    across the HBM<->VMEM boundary, so the heat map's per-region sector
-    temperatures ARE the byte-traffic model — the bridge between the
-    Level-1 profiler and the Level-3 roofline view.
-    """
-    hlo_bytes = 0.0
-    for rh in hm.regions:
-        if rh.region.space != "hbm":
-            continue
-        hlo_bytes += float(
-            int(rh.sector_temps_array.sum()) * rh.region.geometry.sector_bytes
-        )
-    return RooflineTerms(
-        name=name,
-        chips=chips,
-        hlo_flops=flops,
-        hlo_bytes=hlo_bytes,
-        collective_bytes=collective_bytes,
-        model_flops=model_flops,
-    )
-
-
-def from_raw(
-    name: str,
-    chips: int,
-    hlo_flops: float,
-    hlo_bytes: float,
-    collective_bytes: float,
-    model_flops: float = 0.0,
-) -> RooflineTerms:
-    return RooflineTerms(
-        name=name,
-        chips=chips,
-        hlo_flops=hlo_flops,
-        hlo_bytes=hlo_bytes,
-        collective_bytes=collective_bytes,
-        model_flops=model_flops,
-    )

@@ -44,6 +44,17 @@ def sublanes_for(itemsize: int) -> int:
         raise ValueError(f"unsupported itemsize {itemsize}") from e
 
 
+def tile_rows(n_rows: int, itemsize: int) -> int:
+    """Rows of the block a kernel fetches to reach one row of an array.
+
+    One tile row of sublanes; or all ``n_rows`` when they are not a whole
+    number of tile rows, since a block must then span the array (Mosaic's
+    (8, 128) block rule).
+    """
+    sub = sublanes_for(itemsize)
+    return sub if n_rows % sub == 0 else n_rows
+
+
 def words_per_sector(itemsize: int) -> int:
     """Number of 'words' (sublane rows) per 'sector' (native tile)."""
     return sublanes_for(itemsize)

@@ -21,6 +21,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.collector import KernelSpec, OperandSpec, ScratchSpec
+from repro.kernels.mxu import dot_precision
 
 NEG_INF = -1e30
 
@@ -40,7 +41,8 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
         q = q_ref[0]  # (bq, d)
         k = k_ref[0]  # (bkv, d)
         s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32,
+            precision=dot_precision(q.dtype),
         ) * scale  # (bq, bkv)
         if causal:
             qpos = qi * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bkv), 0)
@@ -53,7 +55,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
         l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=-1, keepdims=True)
         acc_ref[...] = acc_ref[...] * corr + jax.lax.dot_general(
             p.astype(v_ref.dtype), v_ref[0], (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
+            preferred_element_type=jnp.float32, precision=dot_precision(v_ref.dtype),
         )
         m_ref[...] = m_new
 
@@ -75,7 +77,7 @@ def flash_attention(
     causal: bool = True,
     bq: int = 128,
     bkv: int = 128,
-    interpret: bool = True,
+    interpret: bool = False,
 ) -> jax.Array:
     bh, sq, d = q.shape
     skv = k.shape[1]

@@ -30,6 +30,8 @@ import numpy as np
 from jax.experimental import pallas as pl
 
 from repro.core.collector import KernelSpec, OperandSpec, ScratchSpec
+from repro.core.tiles import tile_rows
+from repro.kernels.mxu import dot_precision
 
 
 # ---------------------------------------------------------------------------
@@ -37,31 +39,44 @@ from repro.core.collector import KernelSpec, OperandSpec, ScratchSpec
 # ---------------------------------------------------------------------------
 
 
-def _gemm_v00_kernel(a_ref, b_ref, c_ref):
-    # a_ref: (1, K), b_ref: (K, N), c_ref: (1, N)
-    c_ref[...] = jnp.dot(
-        a_ref[...], b_ref[...], preferred_element_type=jnp.float32
+def _gemm_v00_kernel(a_ref, b_ref, c_ref, *, rows: int):
+    # a_ref: (rows, K) tile row holding row i, b_ref: (K, N), c_ref: (rows, N);
+    # program i reads and writes only sublane i % rows of its tiles
+    r = pl.program_id(0) % rows
+    a = a_ref[pl.ds(r, 1), :]
+    c_ref[pl.ds(r, 1), :] = jnp.dot(
+        a, b_ref[...], preferred_element_type=jnp.float32,
+        precision=dot_precision(a.dtype),
     ).astype(c_ref.dtype)
 
 
-def gemm_v00(a: jax.Array, b: jax.Array, interpret: bool = True) -> jax.Array:
+def gemm_v00(a: jax.Array, b: jax.Array, interpret: bool = False) -> jax.Array:
     m, k = a.shape
     k2, n = b.shape
     assert k == k2
+    # A one-row block breaks Mosaic's (8, 128) block rule, and a DMA moves
+    # whole tiles anyway: each program gets the tile row that holds its
+    # row and works on one sublane of it, so `rows` programs share each
+    # A and C tile.
+    rows = tile_rows(m, np.dtype(a.dtype).itemsize)
     return pl.pallas_call(
-        _gemm_v00_kernel,
+        functools.partial(_gemm_v00_kernel, rows=rows),
         grid=(m,),
         in_specs=[
-            pl.BlockSpec((1, k), lambda i: (i, 0)),
+            pl.BlockSpec((rows, k), lambda i: (i // rows, 0)),
             pl.BlockSpec((k, n), lambda i: (0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, n), lambda i: (i, 0)),
+        out_specs=pl.BlockSpec((rows, n), lambda i: (i // rows, 0)),
         out_shape=jax.ShapeDtypeStruct((m, n), a.dtype),
         interpret=interpret,
     )(a, b)
 
 
 def gemm_v00_spec(m: int, n: int, k: int, dtype=np.float32) -> KernelSpec:
+    # gemm_v00 fetches the tile row that holds row i; the spec keeps the
+    # (1, K) row the program uses.  A tile walk charges whole tiles, so
+    # both touch the same tiles, and the row view is where the tuner's
+    # re-tile reads its sublane dim from.
     return KernelSpec(
         name="gemm_v00",
         grid=(m,),
@@ -80,12 +95,13 @@ def gemm_v00_spec(m: int, n: int, k: int, dtype=np.float32) -> KernelSpec:
 
 def _gemm_v01_kernel(a_ref, b_ref, c_ref):
     c_ref[...] = jnp.dot(
-        a_ref[...], b_ref[...], preferred_element_type=jnp.float32
+        a_ref[...], b_ref[...], preferred_element_type=jnp.float32,
+        precision=dot_precision(a_ref.dtype),
     ).astype(c_ref.dtype)
 
 
 def gemm_v01(
-    a: jax.Array, b: jax.Array, bm: int = 8, interpret: bool = True
+    a: jax.Array, b: jax.Array, bm: int = 8, interpret: bool = False
 ) -> jax.Array:
     m, k = a.shape
     _, n = b.shape
@@ -128,7 +144,8 @@ def _gemm_v02_kernel(a_ref, b_ref, c_ref, acc_ref, *, n_k: int):
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     acc_ref[...] += jnp.dot(
-        a_ref[...], b_ref[...], preferred_element_type=jnp.float32
+        a_ref[...], b_ref[...], preferred_element_type=jnp.float32,
+        precision=dot_precision(a_ref.dtype),
     )
 
     @pl.when(ki == n_k - 1)
@@ -142,7 +159,7 @@ def gemm_v02(
     bm: int = 128,
     bn: int = 128,
     bk: int = 128,
-    interpret: bool = True,
+    interpret: bool = False,
 ) -> jax.Array:
     m, k = a.shape
     _, n = b.shape

@@ -23,6 +23,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.collector import KernelSpec, OperandSpec
+from repro.kernels.mxu import dot_precision
 
 
 def plan_groups(group_sizes: np.ndarray, bm: int) -> Tuple[np.ndarray, np.ndarray, int]:
@@ -49,7 +50,8 @@ def plan_groups(group_sizes: np.ndarray, bm: int) -> Tuple[np.ndarray, np.ndarra
 def _gmm_kernel(ids_ref, x_ref, w_ref, o_ref):
     # ids_ref: prefetched scalars (unused in body; consumed by index_map)
     o_ref[...] = jnp.dot(
-        x_ref[...], w_ref[0], preferred_element_type=jnp.float32
+        x_ref[...], w_ref[0], preferred_element_type=jnp.float32,
+        precision=dot_precision(x_ref.dtype),
     ).astype(o_ref.dtype)
 
 
@@ -58,7 +60,7 @@ def gmm(
     w: jax.Array,  # (E, K, N)
     tile_expert_ids: jax.Array,  # (M_padded // bm,) int32
     bm: int = 128,
-    interpret: bool = True,
+    interpret: bool = False,
 ) -> jax.Array:
     m, k = x.shape
     e, _, n = w.shape

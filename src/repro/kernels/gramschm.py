@@ -18,20 +18,31 @@ minor/lane dimension -> contiguous (1, NI) row loads.
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 
 from repro.core.collector import KernelSpec, OperandSpec
+from repro.core.tiles import tile_rows
+from repro.kernels.mxu import dot_precision
 
 
-def _k3_naive_kernel(q_ref, a_ref, r_ref):
-    # q: (NI, 1) column block; a: (NI, BJ); r: (1, BJ)
-    qcol = q_ref[...].astype(jnp.float32)  # (NI, 1)
+def _k3_naive_kernel(q_ref, a_ref, r_ref, *, col: int):
+    # q: (NI, LANE_W) — the lane tile holding column k; a: (NI, BJ); r: (1, BJ)
+    qcol = q_ref[:, col : col + 1].astype(jnp.float32)  # (NI, 1)
     r_ref[...] = jnp.sum(qcol * a_ref[...].astype(jnp.float32), axis=0, keepdims=True).astype(
         r_ref.dtype
     )
+
+
+def _lane_width(nk: int) -> int:
+    """Width of the lane tile a strided column read fetches from q."""
+    lane_w = min(128, nk)
+    assert nk % lane_w == 0
+    return lane_w
 
 
 def gramschm_k3_naive(
@@ -39,16 +50,19 @@ def gramschm_k3_naive(
     a: jax.Array,  # (NI, NJ)
     k: int,
     bj: int = 128,
-    interpret: bool = True,
+    interpret: bool = False,
 ) -> jax.Array:
     ni, nk = q.shape
     _, nj = a.shape
     assert nj % bj == 0
+    # the strided column read: a TPU DMA moves whole lane tiles, so the
+    # block is the (NI, 128) tile column holding column k, sliced in-kernel
+    lane_w = _lane_width(nk)
     return pl.pallas_call(
-        _k3_naive_kernel,
+        functools.partial(_k3_naive_kernel, col=k % lane_w),
         grid=(nj // bj,),
         in_specs=[
-            pl.BlockSpec((ni, 1), lambda j: (0, k)),  # strided column read
+            pl.BlockSpec((ni, lane_w), lambda j: (0, k // lane_w)),
             pl.BlockSpec((ni, bj), lambda j: (0, j)),
         ],
         out_specs=pl.BlockSpec((1, bj), lambda j: (0, j)),
@@ -57,10 +71,12 @@ def gramschm_k3_naive(
     )(q, a)[0]
 
 
-def _k3_opt_kernel(qt_ref, a_ref, r_ref):
-    # qt: (1, NI) contiguous row block; a: (NI, BJ)
-    qrow = qt_ref[...].astype(jnp.float32)  # (1, NI)
-    r_ref[...] = (qrow @ a_ref[...].astype(jnp.float32)).astype(r_ref.dtype)
+def _k3_opt_kernel(qt_ref, a_ref, r_ref, *, row: int):
+    # qt: (ROWS, NI) tile row holding row k; a: (NI, BJ)
+    qrow = qt_ref[row : row + 1, :].astype(jnp.float32)  # (1, NI) contiguous
+    r_ref[...] = jnp.dot(
+        qrow, a_ref[...].astype(jnp.float32), precision=dot_precision(jnp.float32)
+    ).astype(r_ref.dtype)
 
 
 def gramschm_k3_opt(
@@ -68,15 +84,18 @@ def gramschm_k3_opt(
     a: jax.Array,  # (NI, NJ)
     k: int,
     bj: int = 128,
-    interpret: bool = True,
+    interpret: bool = False,
 ) -> jax.Array:
     nk, ni = qt.shape
     _, nj = a.shape
+    # a one-row block breaks Mosaic's (8, 128) block rule: fetch the tile
+    # row that holds row k, whose sublane k % rows is the contiguous row
+    rows = tile_rows(nk, np.dtype(qt.dtype).itemsize)
     return pl.pallas_call(
-        _k3_opt_kernel,
+        functools.partial(_k3_opt_kernel, row=k % rows),
         grid=(nj // bj,),
         in_specs=[
-            pl.BlockSpec((1, ni), lambda j: (k, 0)),  # contiguous row read
+            pl.BlockSpec((rows, ni), lambda j: (k // rows, 0)),  # contiguous row read
             pl.BlockSpec((ni, bj), lambda j: (0, j)),
         ],
         out_specs=pl.BlockSpec((1, bj), lambda j: (0, j)),
@@ -110,7 +129,14 @@ def k3_naive_spec(ni: int, nj: int, nk: int, k: int = 0, bj: int = 128) -> Kerne
 
 
 def k3_naive_block_spec(ni: int, nj: int, nk: int, k: int = 0, bj: int = 128) -> KernelSpec:
-    """2-D block geometry of the naive kernel (transaction model)."""
+    """2-D block geometry of the naive kernel (transaction model).
+
+    The kernel fetches the (NI, 128) lane tile that holds column k; the
+    spec keeps the one-column block it uses.  A tile walk charges whole
+    sublane rows, so both touch the same words of the same tiles, and
+    the column view is what the linter's stride rule and the tuner's
+    transpose read.
+    """
     return KernelSpec(
         name="gramschmidt_kernel3_blocks",
         grid=(nj // bj,),

@@ -17,7 +17,8 @@ one-hot dense accumulation.  Two variants:
     transfer per program over its OWN row, no cross-program tiles.
     Residual inefficiency: each (1, n_bins) partial row is one sublane of
     an (8,128) tile -> 8 programs still share each partials tile (the
-    profiler correctly flags residual false sharing on the stores).
+    kernel writes its sublane of that tile row; the profiler correctly
+    flags residual false sharing on the stores).
   * opt2   — VMEM-scratch accumulator across the sequential grid, ONE
     final store at the last program: the pattern-free end state (TPU's
     sequential-grid analogue of the paper's privatization fix).
@@ -33,6 +34,7 @@ import numpy as np
 from jax.experimental import pallas as pl
 
 from repro.core.collector import KernelSpec, OperandSpec
+from repro.core.tiles import tile_rows
 
 
 def _hist_naive_kernel(cells_ref, hist_ref, *, n_bins: int):
@@ -53,7 +55,7 @@ def hist_naive(
     cells: jax.Array,  # (N,) int32 cell ids
     n_bins: int,
     block: int = 1024,
-    interpret: bool = True,
+    interpret: bool = False,
 ) -> jax.Array:
     n = cells.shape[0]
     assert n % block == 0
@@ -69,26 +71,33 @@ def hist_naive(
     return out[0]
 
 
-def _hist_opt_kernel(cells_ref, part_ref, *, n_bins: int):
+def _hist_opt_kernel(cells_ref, part_ref, *, n_bins: int, rows: int):
+    # part_ref: (rows, n_bins), the tile row holding this program's partial
     cells = cells_ref[...]
     onehot = (
         cells[0][:, None] == jax.lax.broadcasted_iota(jnp.int32, (cells.shape[1], n_bins), 1)
     ).astype(jnp.float32)
-    part_ref[...] = jnp.sum(onehot, axis=0, keepdims=True).astype(part_ref.dtype)
+    part_ref[pl.ds(pl.program_id(0) % rows, 1), :] = jnp.sum(
+        onehot, axis=0, keepdims=True
+    ).astype(part_ref.dtype)
 
 
 def hist_opt(
-    cells: jax.Array, n_bins: int, block: int = 1024, interpret: bool = True
+    cells: jax.Array, n_bins: int, block: int = 1024, interpret: bool = False
 ) -> jax.Array:
     n = cells.shape[0]
     assert n % block == 0
-    kernel = functools.partial(_hist_opt_kernel, n_bins=n_bins)
+    n_blocks = n // block
+    # a one-row block breaks Mosaic's (8, 128) block rule: each program
+    # gets the tile row that holds its partial row and writes one sublane
+    rows = tile_rows(n_blocks, np.dtype(np.float32).itemsize)
+    kernel = functools.partial(_hist_opt_kernel, n_bins=n_bins, rows=rows)
     parts = pl.pallas_call(
         kernel,
-        grid=(n // block,),
+        grid=(n_blocks,),
         in_specs=[pl.BlockSpec((1, block), lambda i: (0, i))],
-        out_specs=pl.BlockSpec((1, n_bins), lambda i: (i, 0)),  # private row
-        out_shape=jax.ShapeDtypeStruct((n // block, n_bins), jnp.float32),
+        out_specs=pl.BlockSpec((rows, n_bins), lambda i: (i // rows, 0)),
+        out_shape=jax.ShapeDtypeStruct((n_blocks, n_bins), jnp.float32),
         interpret=interpret,
     )(cells[None, :])
     return jnp.sum(parts, axis=0)  # XLA tree-reduce
@@ -113,7 +122,7 @@ def _hist_opt2_kernel(cells_ref, hist_ref, acc_ref, *, n_bins: int, n_blocks: in
 
 
 def hist_opt2(
-    cells: jax.Array, n_bins: int, block: int = 1024, interpret: bool = True
+    cells: jax.Array, n_bins: int, block: int = 1024, interpret: bool = False
 ) -> jax.Array:
     from jax.experimental.pallas import tpu as pltpu
 
@@ -160,6 +169,8 @@ def hist_naive_spec(n: int, n_bins: int, block: int = 1024) -> KernelSpec:
 
 
 def hist_opt_spec(n: int, n_bins: int, block: int = 1024) -> KernelSpec:
+    # hist_opt writes one sublane of the tile row that holds its partial;
+    # the spec keeps the (1, n_bins) row, which touches the same tiles
     n_blocks = n // block
     return KernelSpec(
         name="find_cell_counts_opt",

@@ -28,6 +28,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.collector import KernelSpec, OperandSpec, ScratchSpec
+from repro.kernels.mxu import dot_precision
 
 NEG_INF = -1e30
 
@@ -57,7 +58,8 @@ def _paged_decode_kernel(
         q = q_ref[0]  # (H, D)
         k = k_ref[0, 0]  # (page, D)
         s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32,
+            precision=dot_precision(q.dtype),
         ) * scale  # (H, page)
         pos = j * page + jax.lax.broadcasted_iota(
             jnp.int32, s.shape, dimension=1
@@ -70,7 +72,7 @@ def _paged_decode_kernel(
         l_scr[...] = l_scr[...] * corr + jnp.sum(p, axis=-1, keepdims=True)
         acc_scr[...] = acc_scr[...] * corr + jax.lax.dot_general(
             p.astype(v_ref.dtype), v_ref[0, 0], (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
+            preferred_element_type=jnp.float32, precision=dot_precision(v_ref.dtype),
         )
         m_scr[...] = m_new
 
@@ -87,7 +89,7 @@ def paged_decode_attention(
     v_pages: jax.Array,
     block_tables: jax.Array,  # (B, n_slots) int32 physical page ids
     context_lens: jax.Array,  # (B,) int32
-    interpret: bool = True,
+    interpret: bool = False,
 ) -> jax.Array:
     b, h, d = q.shape
     _, _, page, _ = k_pages.shape

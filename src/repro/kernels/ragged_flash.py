@@ -32,6 +32,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.collector import KernelSpec, OperandSpec, ScratchSpec
+from repro.kernels.mxu import dot_precision
 
 NEG_INF = -1e30
 
@@ -61,7 +62,8 @@ def _ragged_decode_kernel(
         q = q_ref[0]  # (H, D)
         k = k_ref[0]  # (bkv, D)
         s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32,
+            precision=dot_precision(q.dtype),
         ) * scale  # (H, bkv)
         kpos = block_start + jax.lax.broadcasted_iota(
             jnp.int32, s.shape, dimension=1
@@ -74,7 +76,7 @@ def _ragged_decode_kernel(
         l_scr[...] = l_scr[...] * corr + jnp.sum(p, axis=-1, keepdims=True)
         acc_scr[...] = acc_scr[...] * corr + jax.lax.dot_general(
             p.astype(v_ref.dtype), v_ref[0], (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
+            preferred_element_type=jnp.float32, precision=dot_precision(v_ref.dtype),
         )
         m_scr[...] = m_new
 
@@ -92,7 +94,7 @@ def ragged_decode_attention(
     starts: jax.Array,  # (B,) int32
     ends: jax.Array,  # (B,) int32
     bkv: int = DEF_BKV,
-    interpret: bool = True,
+    interpret: bool = False,
 ) -> jax.Array:
     b, h, d = q.shape
     s = k.shape[1]

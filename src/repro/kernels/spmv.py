@@ -41,7 +41,7 @@ def spmv_ell(
     vals: jax.Array,  # (R, K) padded per-row values
     xg: jax.Array,  # (R, K) pre-gathered x[colIndices]
     br: int = 8,
-    interpret: bool = True,
+    interpret: bool = False,
 ) -> jax.Array:
     r, k = vals.shape
     assert r % br == 0

@@ -26,36 +26,42 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.collector import KernelSpec, OperandSpec, ScratchSpec
+from repro.kernels.mxu import dot_precision
 
 
 def _ssd_chunk_kernel(x_ref, a_ref, b_ref, c_ref, y_ref, s_ref):
-    # blocks: x (1, L, P), a (1, L), b (1, L, N), c (1, L, N)
-    # outputs: y (1, L, P), s (1, P, N)  — per-chunk end state
+    # blocks: x (1, 1, L, P), a (1, 1, 1, L), b (1, 1, L, N), c (1, 1, L, N)
+    # outputs: y (1, 1, L, P), s (1, 1, P, N)  — per-chunk end state
     x = x_ref[0, 0]  # (L, P)
-    a = a_ref[0, 0].astype(jnp.float32)  # (L,)
+    a = a_ref[0, 0].astype(jnp.float32)  # (1, L) lane row
     bm = b_ref[0, 0]  # (L, N)
     cm = c_ref[0, 0]  # (L, N)
     l = x.shape[0]
-    cum = jnp.cumsum(a)  # (L,)
-    # decay matrix L[i,j] = exp(cum_i - cum_j) for j <= i
-    seg = cum[:, None] - cum[None, :]
     ii = jax.lax.broadcasted_iota(jnp.int32, (l, l), 0)
     jj = jax.lax.broadcasted_iota(jnp.int32, (l, l), 1)
+    # inclusive prefix sums as a column, cum[i] = sum_{k<=i} a[k]: Mosaic
+    # has no cumsum, and a masked row reduction stays exact in f32
+    cum = jnp.sum(jnp.where(jj <= ii, a, 0.0), axis=1, keepdims=True)  # (L, 1)
+    rows = jnp.broadcast_to(cum, (l, l))
+    # decay matrix L[i,j] = exp(cum_i - cum_j) for j <= i
+    seg = rows - rows.T
     dec = jnp.where(jj <= ii, jnp.exp(seg), 0.0)  # (L, L)
     # scores = (C B^T) . dec
     scores = jax.lax.dot_general(
-        cm, bm, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        cm, bm, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32,
+        precision=dot_precision(cm.dtype),
     ) * dec  # (L, L)
     y = jax.lax.dot_general(
         scores.astype(x.dtype), x, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
+        preferred_element_type=jnp.float32, precision=dot_precision(x.dtype),
     )  # (L, P)
     y_ref[0, 0] = y.astype(y_ref.dtype)
     # chunk end state: sum_t exp(cum_L - cum_t) * x_t (outer) b_t -> (P, N)
-    w = jnp.exp(cum[-1] - cum)[:, None]  # (L, 1)
+    w = jnp.exp(jnp.sum(a, axis=1, keepdims=True) - cum)  # (L, 1)
     xw = (x.astype(jnp.float32) * w).astype(x.dtype)
     s = jax.lax.dot_general(
-        xw, bm, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
+        xw, bm, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32,
+        precision=dot_precision(xw.dtype),
     )  # (P, N)
     s_ref[0, 0] = s.astype(s_ref.dtype)
 
@@ -65,18 +71,20 @@ def ssd_chunk(
     a: jax.Array,  # (BH, C, L) log-decays
     bmat: jax.Array,  # (BH, C, L, N)
     cmat: jax.Array,  # (BH, C, L, N)
-    interpret: bool = True,
+    interpret: bool = False,
 ) -> Tuple[jax.Array, jax.Array]:
     """Returns (y_diag (BH,C,L,P), chunk_states (BH,C,P,N))."""
     bh, c, l, p = x.shape
     n = bmat.shape[-1]
     grid = (bh, c)
+    # the log-decays go in as (BH, C, 1, L): one chunk's lane row then
+    # equals the array's last two dims, as Mosaic requires
     y, s = pl.pallas_call(
         _ssd_chunk_kernel,
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, 1, l, p), lambda i, j: (i, j, 0, 0)),
-            pl.BlockSpec((1, 1, l), lambda i, j: (i, j, 0)),
+            pl.BlockSpec((1, 1, 1, l), lambda i, j: (i, j, 0, 0)),
             pl.BlockSpec((1, 1, l, n), lambda i, j: (i, j, 0, 0)),
             pl.BlockSpec((1, 1, l, n), lambda i, j: (i, j, 0, 0)),
         ],
@@ -89,7 +97,7 @@ def ssd_chunk(
             jax.ShapeDtypeStruct((bh, c, p, n), jnp.float32),
         ],
         interpret=interpret,
-    )(x, a, bmat, cmat)
+    )(x, a[:, :, None, :], bmat, cmat)
     return y, s
 
 
@@ -102,8 +110,8 @@ def ssd_chunk_spec(
         operands=(
             OperandSpec("X", (bh, c, l, p), dtype, (1, 1, l, p),
                         lambda i, j: (i, j, 0, 0)),
-            OperandSpec("A", (bh, c, l), dtype, (1, 1, l),
-                        lambda i, j: (i, j, 0)),
+            OperandSpec("A", (bh, c, 1, l), dtype, (1, 1, 1, l),
+                        lambda i, j: (i, j, 0, 0)),
             OperandSpec("B", (bh, c, l, n), dtype, (1, 1, l, n),
                         lambda i, j: (i, j, 0, 0)),
             OperandSpec("C", (bh, c, l, n), dtype, (1, 1, l, n),

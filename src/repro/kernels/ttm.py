@@ -55,7 +55,7 @@ def ttm(
     urows: jax.Array,  # (F, NF, R) pre-gathered U rows
     bf: int = 8,
     use_scratch: bool = False,
-    interpret: bool = True,
+    interpret: bool = False,
 ) -> jax.Array:
     f, nf = vals.shape
     r = urows.shape[-1]

@@ -340,7 +340,7 @@ def run_cell(arch_id: str, shape_name: str, multi_pod: bool, *, sp: bool = True,
     from repro.parallel.context import use_rules
 
     rules = meta.pop("_rules")
-    with mesh, use_rules(rules):
+    with mesh, use_rules(rules, mesh):
         lowered = fn.lower(*args)
         t_lower = time.time() - t0
         t1 = time.time()
@@ -354,6 +354,7 @@ def run_cell(arch_id: str, shape_name: str, multi_pod: bool, *, sp: bool = True,
         cost = hlo_cost.analyze(hlo_text, total_devices=chips)
     terms = roofline.RooflineTerms(
         name=f"{arch_id}/{shape_name}",
+        device_kind=roofline.V5E,  # modeled: the CPU stands in for v5e
         chips=chips,
         hlo_flops=cost.flops,
         hlo_bytes=cost.bytes,

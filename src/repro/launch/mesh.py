@@ -3,35 +3,22 @@ never touches jax device state)."""
 
 from __future__ import annotations
 
-from typing import Optional, Tuple
+from typing import Tuple
 
 import jax
+from jax.sharding import AxisType
 
 
-def mesh_axis_types(n: int) -> dict:
-    """Version-tolerant ``axis_types`` kwargs for ``jax.make_mesh``.
-
-    Newer JAX releases expose ``jax.sharding.AxisType`` and accept an
-    ``axis_types=`` keyword on ``jax.make_mesh``; the pinned JAX in this
-    repo's image predates both.  Returns ``{"axis_types": (Auto,) * n}``
-    when the enum exists and ``{}`` otherwise, so call sites can always
-    write ``jax.make_mesh(shape, axes, **mesh_axis_types(len(axes)))``.
-    """
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * n}
+def make_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...]):
+    """A mesh whose axes are all ``Auto`` (GSPMD propagates shardings)."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     """16x16 single pod (256 chips) or 2x16x16 multi-pod (512 chips)."""
-    shape = (2, 16, 16) if multi_pod else (16, 16)
-    axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **mesh_axis_types(len(axes)))
-
-
-def make_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...]):
-    return jax.make_mesh(shape, axes, **mesh_axis_types(len(axes)))
+    if multi_pod:
+        return make_mesh((2, 16, 16), ("pod", "data", "model"))
+    return make_mesh((16, 16), ("data", "model"))
 
 
 def data_axes_of(mesh) -> Tuple[str, ...]:

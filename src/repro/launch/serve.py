@@ -13,6 +13,7 @@ from typing import Any, Dict
 import jax
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs import get_config
 from repro.models import build_model
 from repro.runtime import Request, ServeConfig, Server
@@ -30,6 +31,7 @@ def main(argv=None) -> Dict[str, Any]:
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
 
+    enable_compile_cache()
     cfg = get_config(args.arch, smoke=args.smoke)
     model = build_model(cfg)
     params = model.init(jax.random.key(args.seed))

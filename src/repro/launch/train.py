@@ -21,8 +21,9 @@ import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.checkpoint import CheckpointManager
+from repro.compile_cache import enable_compile_cache
 from repro.configs import get_config
-from repro.launch.mesh import mesh_axis_types
+from repro.launch.mesh import make_mesh
 from repro.data import DataConfig, SyntheticSource, TokenPipeline
 from repro.models import build_model
 from repro.optim import adamw, cosine_warmup
@@ -46,9 +47,7 @@ def make_mesh_from_devices():
     # squarest (data, model) factorization
     for m in range(int(n**0.5), 0, -1):
         if n % m == 0:
-            return jax.make_mesh(
-                (n // m, m), ("data", "model"), **mesh_axis_types(2)
-            )
+            return make_mesh((n // m, m), ("data", "model"))
     return None
 
 
@@ -67,6 +66,7 @@ def main(argv=None) -> Dict[str, Any]:
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
 
+    enable_compile_cache()
     cfg = get_config(args.arch, smoke=args.smoke)
     model = build_model(cfg)
     mesh = make_mesh_from_devices()

@@ -1,11 +1,10 @@
 """Attention: GQA/MHA (chunked flash-in-XLA), KV caches, sliding window, MLA.
 
-Two execution paths:
-  * ``use_pallas=True``  — the Pallas flash kernel in ``repro.kernels.flash``
-    (TPU target; validated in interpret mode on CPU).
-  * ``use_pallas=False`` — ``flash_xla``: an online-softmax scan over KV
-    chunks.  Same memory behaviour class as flash attention (O(S) live
-    activations instead of O(S^2)), pure XLA, used by the dry-run.
+One execution path, ``flash_xla``: an online-softmax scan over KV chunks
+with a custom VJP.  Same memory behaviour class as flash attention (O(S)
+live activations instead of O(S^2)), pure XLA, on every backend.  The
+Pallas flash kernel in ``repro.kernels.flash`` is not called from here;
+the profiler models it for each attention layer (``repro.models.registry``).
 
 KV caches are plain dicts of arrays + a scalar length; decode updates are
 ``dynamic_update_slice`` so a serve step compiles to a fixed shape.

@@ -214,13 +214,11 @@ def moe_apply_ep(
     GSPMD-routed capacity path whose scatter lowered to ~10x the wire
     bytes on deepseek-v3 train_4k (see EXPERIMENTS.md §Perf).
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
-    from repro.parallel.context import active_rules
-    from repro.parallel.context import _mesh_from_spec
+    from repro.parallel.context import active_mesh, active_rules
 
-    mesh = _mesh_from_spec()
+    mesh = active_mesh()
     rules = active_rules()
     if (
         mesh is None
@@ -309,12 +307,12 @@ def moe_apply_ep(
 
     xspec = P(bpart, "model", None)
     wspec = P(ep_axes if len(ep_axes) > 1 else ep_axes[0], None, None)
-    y, aux = shard_map(
+    y, aux = jax.shard_map(
         local_fn,
         mesh=mesh,
         in_specs=(P(None, None), wspec, wspec, wspec, xspec),
         out_specs=(xspec, P()),
-        check_rep=False,
+        check_vma=False,
     )(
         params["router"].astype(x.dtype),
         params["w_gate"].astype(x.dtype),

@@ -53,12 +53,23 @@ class Server:
         self.key = jax.random.key(cfg.seed)
         # per-slot caches: one cache tree of batch = slots
         self.caches = model.init_caches(cfg.batch_slots, cfg.max_seq, dtype=dtype)
-        self._decode = jax.jit(model.decode_step)
-        self._prefill_one = jax.jit(
-            lambda p, t, c: model.prefill(p, t, c), static_argnums=()
-        )
+        # traces of each jitted entry, i.e. its compiles: prefill retraces
+        # for every new prompt length
+        self.compiles: Dict[str, int] = {"prefill": 0, "decode": 0}
+
+        def decode(p, t, c):
+            self.compiles["decode"] += 1
+            return model.decode_step(p, t, c)
+
+        def prefill(p, t, c):
+            self.compiles["prefill"] += 1
+            return model.prefill(p, t, c)
+
+        self._decode = jax.jit(decode)
+        self._prefill_one = jax.jit(prefill)
         self.slot_tokens = np.zeros((cfg.batch_slots, 1), np.int32)
         self.steps = 0
+        self.last_logits: Optional[jax.Array] = None  # (slots, 1, V) of the last tick
 
     # -- queue ------------------------------------------------------------
 
@@ -114,6 +125,7 @@ class Server:
         logits, self.caches = self._decode(
             self.params, jnp.asarray(self.slot_tokens), self.caches
         )
+        self.last_logits = logits
         self.steps += 1
         for slot, req in enumerate(self.active):
             if req is None:

@@ -62,13 +62,12 @@ def test_constrain_logical_annotates_under_mesh():
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, jax.numpy as jnp, json
-from repro.launch.mesh import mesh_axis_types
+from repro.launch.mesh import make_mesh
 from repro.parallel.context import use_rules, constrain_logical
 from repro.parallel.sharding import make_rules
-mesh = jax.make_mesh((2, 4), ("data", "model"),
-                     **mesh_axis_types(2))
+mesh = make_mesh((2, 4), ("data", "model"))
 rules = make_rules()
-with mesh, use_rules(rules):
+with mesh, use_rules(rules, mesh):
     def f(x):
         return constrain_logical(x, ("act_batch", None, "vocab")) * 2
     txt = jax.jit(f).lower(jax.ShapeDtypeStruct((8, 4, 64), jnp.float32)).as_text()
@@ -91,7 +90,7 @@ def test_ep_two_axis_expert_sharding_parity():
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, jax.numpy as jnp, json
-from repro.launch.mesh import mesh_axis_types
+from repro.launch.mesh import make_mesh
 from repro.models.moe import MoEConfig, moe_defs, moe_apply_ep, moe_ref
 from repro.models.params import init_params
 from repro.parallel.context import use_rules
@@ -101,10 +100,9 @@ cfg = MoEConfig(d_model=16, d_ff=32, n_experts=8, top_k=2,
 params = init_params(moe_defs(cfg), jax.random.key(0))
 x = jax.random.normal(jax.random.key(1), (4, 8, 16))
 y_ref, _ = moe_ref(params, x, cfg)
-mesh = jax.make_mesh((2, 4), ("data", "model"),
-                     **mesh_axis_types(2))
+mesh = make_mesh((2, 4), ("data", "model"))
 rules = make_rules(expert_axes=("model", "data"))  # 8 experts over 8 chips
-with mesh, use_rules(rules):
+with mesh, use_rules(rules, mesh):
     y, aux = jax.jit(lambda p, x: moe_apply_ep(p, x, cfg))(params, x)
 print(json.dumps({"diff": float(jnp.abs(y - y_ref).max())}))
 """

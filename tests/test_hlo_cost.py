@@ -154,3 +154,52 @@ ENTRY %main (x: f32[1024]) -> f32[1024] {
     cost = hlo_cost.analyze(text, total_devices=8)
     # all-reduce: 2*(g-1)/g*B with g=2, B=4096 bytes -> 4096/iter x 5 iters
     assert cost.wire_bytes == pytest.approx(5 * 4096, rel=0.01)
+
+
+# -- TPU-compiled HLO: dots written as convolutions, tiled layouts ----------
+
+_TPU_CONV_MODULE = """HloModule m
+
+ENTRY %main.1 (p0: f32[2,64,128], p1: f32[512,128,1]) -> f32[2,64,512] {
+  %p0 = f32[2,64,128]{2,1,0:T(8,128)} parameter(0)
+  %p1 = f32[512,128,1]{1,0,2:T(8,128)} parameter(1)
+  ROOT %convolution.24 = f32[2,64,512]{2,1,0:T(8,128)S(1)} convolution(%p0, %p1), window={size=1}, dim_labels=0bf_oi0->0bf
+}
+"""
+
+_TPU_PADDED_CONV_MODULE = """HloModule m
+
+ENTRY %main.1 (p0: f32[1,4,8], p1: f32[4,8,16]) -> f32[1,4,16] {
+  %p0 = f32[1,4,8]{2,1,0:T(8,128)} parameter(0)
+  %p1 = f32[4,8,16]{2,1,0:T(8,128)} parameter(1)
+  ROOT %convolution.1 = f32[1,4,16]{2,1,0:T(8,128)} convolution(%p0, %p1), window={size=4 pad=3_0}, dim_labels=b0f_0io->b0f
+}
+"""
+
+
+@pytest.mark.parametrize(
+    "text,flops",
+    [
+        # a (128 x 128) @ (128 x 512) dot: 2 * 128 * 128 * 512
+        (_TPU_CONV_MODULE, 2 * 128 * 128 * 512),
+        # causal window of 4 over 4 positions: 1+2+3+4 = 10 real taps of
+        # 8 input features for each of 16 output features
+        (_TPU_PADDED_CONV_MODULE, 2 * 16 * 8 * 10),
+    ],
+    ids=["dot-as-conv", "padded-window"],
+)
+def test_tpu_convolution_flops(text, flops):
+    assert hlo_cost.analyze(text).flops == flops
+
+
+def test_thermo_reads_tuple_shapes_with_tiled_layouts():
+    from repro.core.hlo_thermo import analyze_hlo
+
+    line = (
+        "  %all-reduce.1 = (f32[8]{0:T(256)}, bf16[4,128]{1,0:T(8,128)(2,1)}) "
+        "all-reduce(%a, %b), replica_groups={{0,1}}, to_apply=%add"
+    )
+    heat = analyze_hlo(line)
+    assert heat.collective_count == 1
+    # 8 f32 + 512 bf16 = 1056 bytes; a 2-way all-reduce moves 2 * 1/2 of it
+    assert heat.collective_bytes == 1056.0

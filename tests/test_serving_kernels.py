@@ -38,7 +38,7 @@ def test_ragged_decode_matches_reference():
     ctx = RF.ragged_context(b, s)
     starts = jnp.asarray(ctx["starts"])
     ends = jnp.asarray(ctx["ends"])
-    got = RF.ragged_decode_attention(q, k, v, starts, ends, bkv=32)
+    got = RF.ragged_decode_attention(q, k, v, starts, ends, bkv=32, interpret=True)
     want = RF.ragged_decode_reference(q, k, v, starts, ends)
     np.testing.assert_allclose(got, want, atol=2e-5, rtol=2e-4)
 
@@ -49,8 +49,8 @@ def test_ragged_decode_block_size_invariance():
     q, k, v = _rand(0, (b, h, d)), _rand(1, (b, s, d)), _rand(2, (b, s, d))
     starts = jnp.asarray([0, 16], jnp.int32)
     ends = jnp.asarray([100, 128], jnp.int32)
-    a = RF.ragged_decode_attention(q, k, v, starts, ends, bkv=32)
-    bb = RF.ragged_decode_attention(q, k, v, starts, ends, bkv=64)
+    a = RF.ragged_decode_attention(q, k, v, starts, ends, bkv=32, interpret=True)
+    bb = RF.ragged_decode_attention(q, k, v, starts, ends, bkv=64, interpret=True)
     np.testing.assert_allclose(a, bb, atol=2e-5, rtol=2e-4)
 
 
@@ -63,7 +63,7 @@ def test_paged_decode_matches_reference():
     ctx = PA.paged_context(b, pages, slots, page)
     tables = jnp.asarray(ctx["block_tables"])
     lens = jnp.asarray(ctx["context_lens"])
-    got = PA.paged_decode_attention(q, k_pages, v_pages, tables, lens)
+    got = PA.paged_decode_attention(q, k_pages, v_pages, tables, lens, interpret=True)
     want = PA.paged_decode_reference(q, k_pages, v_pages, tables, lens)
     np.testing.assert_allclose(got, want, atol=2e-5, rtol=2e-4)
 
@@ -78,13 +78,13 @@ def test_paged_decode_table_permutation_invariance():
     v_pages = _rand(2, (1, pages, page, d))
     tables = jnp.asarray([[0, 1], [2, 3]], jnp.int32)
     lens = jnp.asarray([48, 64], jnp.int32)
-    base = PA.paged_decode_attention(q, k_pages, v_pages, tables, lens)
+    base = PA.paged_decode_attention(q, k_pages, v_pages, tables, lens, interpret=True)
     perm = np.asarray([5, 3, 7, 0, 2, 6, 1, 4])
     k2 = k_pages[:, perm]
     v2 = v_pages[:, perm]
     inv = np.argsort(perm)
     tables2 = jnp.asarray(inv[np.asarray(tables)], jnp.int32)
-    moved = PA.paged_decode_attention(q, k2, v2, tables2, lens)
+    moved = PA.paged_decode_attention(q, k2, v2, tables2, lens, interpret=True)
     np.testing.assert_allclose(base, moved, atol=2e-5, rtol=2e-4)
 
 

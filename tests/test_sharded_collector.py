@@ -386,3 +386,16 @@ def test_pool_sharded_analyze_matches_serial():
         (s.lo, s.hi) for s in sharded2.shards
     ]
     assert len(sharded.shards) == 2
+
+
+def test_pool_workers_hold_jax_to_the_cpu(monkeypatch):
+    """Workers only walk grids: their JAX is pinned to the CPU, so a
+    worker never reaches for the accelerator the parent holds."""
+    import os
+
+    # the workers inherit no platform choice from this process
+    monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+    with ShardedCollector(1) as sc:
+        pool = sc._ensure_pool()
+        assert pool.submit(os.getenv, "JAX_PLATFORMS").result() == "cpu"
+    assert "JAX_PLATFORMS" not in os.environ  # the parent is untouched

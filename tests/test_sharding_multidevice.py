@@ -17,7 +17,7 @@ import numpy as np
 import pytest
 from jax.sharding import PartitionSpec as P
 
-from repro.launch.mesh import mesh_axis_types
+from repro.launch.mesh import make_mesh
 from repro.parallel.sharding import Rules, fixup_specs, make_rules, specs_from_logical
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -42,7 +42,7 @@ def test_extra_rules_take_precedence():
 
 
 def test_fixup_drops_nondivisible():
-    mesh = jax.make_mesh((1,), ("model",), **mesh_axis_types(1))
+    mesh = make_mesh((1,), ("model",))
     # fake a 16-wide model axis via a Mesh-like shim
     class FakeMesh:
         shape = {"model": 16, "data": 16}
@@ -60,7 +60,7 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, jax.numpy as jnp, numpy as np, json
 from jax.sharding import NamedSharding, PartitionSpec as P
-from repro.launch.mesh import mesh_axis_types
+from repro.launch.mesh import make_mesh
 """
 
 
@@ -100,8 +100,7 @@ def test_sharded_train_step_matches_single_device():
     st1, met1 = step(st, toks, labs)
 
     # 8-device (2 data x 4 model) mesh
-    mesh = jax.make_mesh((2, 4), ("data", "model"),
-                         **mesh_axis_types(2))
+    mesh = make_mesh((2, 4), ("data", "model"))
     rules = make_rules()
     pspecs = fixup_specs(specs_from_logical(m.logical_specs(), rules), params, mesh)
     psh = jax.tree.map(lambda s: NamedSharding(mesh, s), pspecs,
@@ -132,10 +131,9 @@ def test_ep_moe_matches_reference_on_mesh():
     x = jax.random.normal(jax.random.key(1), (4, 8, 16))
     y_ref, aux_ref = moe_ref(params, x, cfg)
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"),
-                         **mesh_axis_types(2))
+    mesh = make_mesh((2, 4), ("data", "model"))
     rules = make_rules()
-    with mesh, use_rules(rules):
+    with mesh, use_rules(rules, mesh):
         y, aux = jax.jit(lambda p, x: moe_apply_ep(p, x, cfg))(params, x)
     diff = float(jnp.abs(y - y_ref).max())
     print(json.dumps({"diff": diff, "aux": float(aux), "aux_ref": float(aux_ref)}))
@@ -147,8 +145,7 @@ def test_pipeline_parallel_matches_sequential():
     res = _run_sub("""
     from repro.parallel.pipeline import pipeline, bubble_fraction
 
-    mesh = jax.make_mesh((4,), ("stage",),
-                         **mesh_axis_types(1))
+    mesh = make_mesh((4,), ("stage",))
     n_stages, n_micro, dim = 4, 8, 16
     ws = jax.random.normal(jax.random.key(0), (n_stages, dim, dim)) * 0.3
     mbs = jax.random.normal(jax.random.key(1), (n_micro, 4, dim))
