@@ -1,4 +1,4 @@
-"""Benchmark aggregator: one section per paper table + roofline.
+"""Benchmark aggregator: one section per paper table, on the CPU.
 
     PYTHONPATH=src python -m benchmarks.run
 
@@ -19,8 +19,6 @@ def main() -> None:
     from benchmarks import (
         bench_overhead,
         bench_patterns,
-        bench_roofline,
-        bench_speedup,
         bench_tune,
     )
     from repro.core.collector import ShardedCollector
@@ -42,14 +40,12 @@ def main() -> None:
                 "overhead (paper Table II)",
                 lambda: bench_overhead.run_all(collector=collector),
             ),
-            ("speedup (paper Table III)", bench_speedup.run),
             # closes the tuning loop per family on the same warm pool;
             # writes BENCH_tune.json
             (
                 "autotuner (closed loop)",
                 lambda: bench_tune.run_all(collector=collector),
             ),
-            ("roofline (§Roofline)", bench_roofline.run),
         ):
             print(f"\n===== {name} =====")
             try:

@@ -186,7 +186,7 @@ def serve(cfg, seed: int, prompt_len: int = PROMPT_LEN) -> None:
     if not all(r.done and len(r.out_tokens) == NEW_TOKENS for r in reqs):
         raise AssertionError("not every request was answered in full")
     log(f"[serve] {REQUESTS} requests x {NEW_TOKENS} new tokens on {SLOTS} slots, "
-        f"prompts of {prompt_len}: {srv.steps} decode ticks, "
+        f"prompts of {prompt_len}: {srv.counters['decode_ticks']} decode ticks, "
         f"{wall:.2f}s including compiles, compiles {srv.compiles}")
     if srv.compiles != {"prefill": 1, "decode": 1}:
         raise AssertionError(f"expected one prefill and one decode compile: {srv.compiles}")

@@ -38,9 +38,10 @@ def main() -> None:
     srv.run_until_done()
     dt = time.perf_counter() - t0
     total = n_requests * 12
+    ticks = srv.counters["decode_ticks"]
     print(f"{n_requests} requests x 12 tokens in {dt:.2f}s "
-          f"({total / dt:.1f} tok/s, {srv.steps} decode ticks, "
-          f"{total / max(srv.steps, 1):.1f} tokens/tick batching efficiency)")
+          f"({total / dt:.1f} tok/s, {ticks} decode ticks, "
+          f"{total / max(ticks, 1):.1f} tokens/tick batching efficiency)")
 
 
 if __name__ == "__main__":

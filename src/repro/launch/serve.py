@@ -53,9 +53,10 @@ def main(argv=None) -> Dict[str, Any]:
     srv.run_until_done()
     dt = time.perf_counter() - t0
     tokens = args.requests * args.max_tokens
+    ticks = srv.counters["decode_ticks"]
     print(f"[serve] {args.requests} requests, {tokens} tokens in {dt:.2f}s "
-          f"({tokens/dt:.1f} tok/s), {srv.steps} decode ticks")
-    return {"tokens": tokens, "seconds": dt, "ticks": srv.steps}
+          f"({tokens/dt:.1f} tok/s), {ticks} decode ticks")
+    return {"tokens": tokens, "seconds": dt, "ticks": ticks}
 
 
 if __name__ == "__main__":

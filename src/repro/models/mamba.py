@@ -249,13 +249,14 @@ def mamba_apply(
         bh = _broadcast_groups(bm, cfg)[:, 0]
         ch = _broadcast_groups(cm, cfg)[:, 0]
         dt_t = dt[:, 0]  # (B,H)
-        y_t, ssm_state = ssd_decode_step(
-            cache["ssm"],
-            (xh * dt_t[..., None]).astype(jnp.float32),
-            a_neg[None] * dt_t,
-            bh.astype(jnp.float32),
-            ch.astype(jnp.float32),
-        )
+        with jax.named_scope("scan"):  # the SSD recurrence, apart from the projections
+            y_t, ssm_state = ssd_decode_step(
+                cache["ssm"],
+                (xh * dt_t[..., None]).astype(jnp.float32),
+                a_neg[None] * dt_t,
+                bh.astype(jnp.float32),
+                ch.astype(jnp.float32),
+            )
         y_t = y_t + params["D"].astype(jnp.float32)[None, :, None] * xh
         y = y_t.reshape(b, 1, cfg.d_inner).astype(x.dtype)
         new_cache = {"conv": conv_state, "ssm": ssm_state}
@@ -265,13 +266,14 @@ def mamba_apply(
         xh = xs.reshape(b, s, cfg.n_heads, cfg.head_dim)
         bh = _broadcast_groups(bm, cfg)
         ch = _broadcast_groups(cm, cfg)
-        y4, final_state = ssd_ref(
-            (xh * dt[..., None]).astype(jnp.float32),
-            a_neg[None, None] * dt,
-            bh.astype(jnp.float32),
-            ch.astype(jnp.float32),
-            chunk=min(cfg.chunk, s),
-        )
+        with jax.named_scope("scan"):
+            y4, final_state = ssd_ref(
+                (xh * dt[..., None]).astype(jnp.float32),
+                a_neg[None, None] * dt,
+                bh.astype(jnp.float32),
+                ch.astype(jnp.float32),
+                chunk=min(cfg.chunk, s),
+            )
         y4 = y4 + params["D"].astype(jnp.float32)[None, None, :, None] * xh
         y = y4.reshape(b, s, cfg.d_inner).astype(x.dtype)
         new_cache = None

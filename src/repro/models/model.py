@@ -296,27 +296,29 @@ class LM:
         if positions is None:
             start = caches_length(caches) if caches is not None else 0
             positions = self._positions(tokens, start)
-        x = embed(params["embed"], tokens).astype(cfg.dtype)
-        if embeddings is not None:
-            x = x + embeddings.astype(cfg.dtype)
-        # the gather from the vocab-sharded embedding leaves x with no
-        # sharding for GSPMD to propagate — constrain it explicitly
-        # (measured 87.7 -> 6.0 GiB/chip on whisper train_4k)
         from repro.parallel.context import constrain_logical
 
-        x = constrain_logical(x, ("act_batch", "act_seq", None))
+        with jax.named_scope("embed"):
+            x = embed(params["embed"], tokens).astype(cfg.dtype)
+            if embeddings is not None:
+                x = x + embeddings.astype(cfg.dtype)
+            # the gather from the vocab-sharded embedding leaves x with no
+            # sharding for GSPMD to propagate — constrain it explicitly
+            # (measured 87.7 -> 6.0 GiB/chip on whisper train_4k)
+            x = constrain_logical(x, ("act_batch", "act_seq", None))
         x, new_caches, aux = stack_apply(
             params["stack"], x, positions, self.stack_cfg, caches
         )
-        if last_only:
-            x = x[:, -1:]  # slice BEFORE the (B,S,vocab) unembed matmul
-        x = rmsnorm(params["final_norm"], x, cfg.norm_eps)
-        if cfg.tie_embeddings:
-            logits = unembed(params["embed"], x)
-        else:
-            logits = (x @ params["unembed"]["w_out"].astype(x.dtype)).astype(
-                jnp.float32
-            )
+        with jax.named_scope("unembed"):  # the final norm and the logits
+            if last_only:
+                x = x[:, -1:]  # slice BEFORE the (B,S,vocab) unembed matmul
+            x = rmsnorm(params["final_norm"], x, cfg.norm_eps)
+            if cfg.tie_embeddings:
+                logits = unembed(params["embed"], x)
+            else:
+                logits = (x @ params["unembed"]["w_out"].astype(x.dtype)).astype(
+                    jnp.float32
+                )
         return logits, new_caches, aux
 
     # -- loss --------------------------------------------------------------------

@@ -142,6 +142,10 @@ def block_defs(cfg: StackConfig, kind: BlockKind) -> Dict[str, Any]:
     return defs
 
 
+# the named scope of each mixer kind in a profile
+MIXER_SCOPES = {"attn": "attn", "mla": "mla", "mamba": "ssm"}
+
+
 def block_apply(
     params: Dict[str, Any],
     x: jax.Array,
@@ -154,28 +158,33 @@ def block_apply(
     aux = jnp.zeros((), jnp.float32)
     if cfg.act_constraint is not None:
         x = cfg.act_constraint(x)
-    h = _norm(cfg, params["norm_mixer"], x)
-    if kind.mixer == "attn":
-        y, new_cache = attn_mod.attn_apply(params["attn"], h, positions, cfg.attn, cache)
-    elif kind.mixer == "mla":
-        pos1d = positions if positions.ndim == 2 else positions[..., 0]
-        y, new_cache = attn_mod.mla_apply(params["mla"], h, pos1d, cfg.mla, cache)
-    else:  # mamba
-        y, new_cache = mamba_mod.mamba_apply(params["mamba"], h, cfg.ssm, cache)
-    x = x + y
-    if kind.ffn == "mlp":
-        h = _norm(cfg, params["norm_ffn"], x)
-        if cfg.mlp_kind == "gelu":
-            from .layers import gelu_mlp
-
-            x = x + gelu_mlp(params["mlp"], h)
-        else:
-            x = x + swiglu(params["mlp"], h)
-    elif kind.ffn == "moe":
-        h = _norm(cfg, params["norm_ffn"], x)
-        y, moe_aux = moe_mod.moe_apply(params["moe"], h, cfg.moe)
+    # each sublayer under the scope of its kind, so that a profile of the
+    # step can split device time by layer (``attn/``, ``mlp/`` ...)
+    with jax.named_scope(MIXER_SCOPES[kind.mixer]):
+        h = _norm(cfg, params["norm_mixer"], x)
+        if kind.mixer == "attn":
+            y, new_cache = attn_mod.attn_apply(params["attn"], h, positions, cfg.attn, cache)
+        elif kind.mixer == "mla":
+            pos1d = positions if positions.ndim == 2 else positions[..., 0]
+            y, new_cache = attn_mod.mla_apply(params["mla"], h, pos1d, cfg.mla, cache)
+        else:  # mamba
+            y, new_cache = mamba_mod.mamba_apply(params["mamba"], h, cfg.ssm, cache)
         x = x + y
-        aux = aux + moe_aux
+    if kind.ffn == "mlp":
+        with jax.named_scope("mlp"):
+            h = _norm(cfg, params["norm_ffn"], x)
+            if cfg.mlp_kind == "gelu":
+                from .layers import gelu_mlp
+
+                x = x + gelu_mlp(params["mlp"], h)
+            else:
+                x = x + swiglu(params["mlp"], h)
+    elif kind.ffn == "moe":
+        with jax.named_scope("moe"):
+            h = _norm(cfg, params["norm_ffn"], x)
+            y, moe_aux = moe_mod.moe_apply(params["moe"], h, cfg.moe)
+            x = x + y
+            aux = aux + moe_aux
     if cfg.act_constraint is not None:
         # constrain the OUTPUT too: the scan carry is what AD stashes per
         # layer — leaving it unconstrained lets propagation pick a
@@ -284,27 +293,30 @@ def stack_apply(
                 new_pcache[sub] = nc
         return x, new_pcache, aux
 
-    for si, (pattern, repeats) in enumerate(segments(cfg.layout)):
-        seg = f"seg{si}"
-        pparams = params[seg]
-        pcache = caches.get(seg) if caches is not None else None
-        if repeats == 1:
-            x, nc, aux1 = one_pattern(pparams, x, pattern, pcache)
-            aux_total = aux_total + aux1
+    # the layer loop's own work (each layer's weight and cache slices, the
+    # stacked cache written back) under ``stack``; each block's under its kind
+    with jax.named_scope("stack"):
+        for si, (pattern, repeats) in enumerate(segments(cfg.layout)):
+            seg = f"seg{si}"
+            pparams = params[seg]
+            pcache = caches.get(seg) if caches is not None else None
+            if repeats == 1:
+                x, nc, aux1 = one_pattern(pparams, x, pattern, pcache)
+                aux_total = aux_total + aux1
+                if new_caches is not None:
+                    new_caches[seg] = nc
+                continue
+
+            def body(carry, xs):
+                x, aux = carry
+                p_slice, c_slice = xs
+                x, nc, aux1 = one_pattern(p_slice, x, pattern, c_slice)
+                return (x, aux + aux1), nc
+
+            body_fn = jax.checkpoint(body) if cfg.remat == "full" else body
+            (x, aux_total), nc_stacked = jax.lax.scan(
+                body_fn, (x, aux_total), (pparams, pcache)
+            )
             if new_caches is not None:
-                new_caches[seg] = nc
-            continue
-
-        def body(carry, xs):
-            x, aux = carry
-            p_slice, c_slice = xs
-            x, nc, aux1 = one_pattern(p_slice, x, pattern, c_slice)
-            return (x, aux + aux1), nc
-
-        body_fn = jax.checkpoint(body) if cfg.remat == "full" else body
-        (x, aux_total), nc_stacked = jax.lax.scan(
-            body_fn, (x, aux_total), (pparams, pcache)
-        )
-        if new_caches is not None:
-            new_caches[seg] = nc_stacked
+                new_caches[seg] = nc_stacked
     return x, new_caches, aux_total

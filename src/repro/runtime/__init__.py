@@ -1,6 +1,6 @@
 """repro.runtime — training loop, serving loop, fault tolerance."""
 
-from . import fault, serve, train_loop
+from . import fault, serve, spans, train_loop
 from .fault import Preempted, PreemptionHandler, StragglerMonitor, retry
 from .serve import Request, ServeConfig, Server
 from .train_loop import TrainConfig, TrainState, build_train_step, init_state, run
@@ -20,5 +20,6 @@ __all__ = [
     "retry",
     "run",
     "serve",
+    "spans",
     "train_loop",
 ]

@@ -5,18 +5,29 @@ prefill fills each request's cache slice; ``decode_step`` advances every
 active slot one token; finished slots (EOS or max_tokens) are freed and
 refilled from the queue — continuous batching at slot granularity.
 
-This is the end-to-end driver for the ``serve_*`` shapes; the dry-run
-lowers the same ``decode_step`` for the production meshes.
+While ``jax.profiler`` traces, every step writes host spans into the
+trace (``serve.step``, ``serve.admit``, ``serve.prefill``,
+``serve.splice``, ``serve.sample``, ``serve.decode``, and ``gc`` around
+collections; see ``spans.py``), so each device-idle gap can be put down
+to what the host was doing.  ``Server.counters`` counts prefill calls
+and the prompt tokens they served, decode ticks and the slots they
+served, collections and the slowest step, for an operator to read
+between steps.
+
+The dry-run lowers the same ``decode_step`` for the production meshes.
 """
 
 from __future__ import annotations
 
 import dataclasses
+import time
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from .spans import gc_counters, span, watch_gc
 
 PyTree = Any
 
@@ -68,8 +79,19 @@ class Server:
         self._decode = jax.jit(decode)
         self._prefill_one = jax.jit(prefill)
         self.slot_tokens = np.zeros((cfg.batch_slots, 1), np.int32)
-        self.steps = 0
         self.last_logits: Optional[jax.Array] = None  # (slots, 1, V) of the last tick
+        # prefill calls (one request each) and the prompt tokens they
+        # served, decode ticks and the slots they served, collections by
+        # generation, and the slowest step since an operator last set
+        # ``step_max_s`` to 0.  Each call and tick computes ``batch_slots``
+        # rows, so the padding is slots x calls - calls, and slots x ticks
+        # - ``decode_rows_used``.
+        watch_gc()
+        self.counters: Dict[str, float] = {
+            "prefill_calls": 0, "prefill_tokens_used": 0,
+            "decode_ticks": 0, "decode_rows_used": 0,
+            **gc_counters(), "step_max_s": 0.0, "step_max_tick": -1,
+        }
 
     # -- queue ------------------------------------------------------------
 
@@ -78,32 +100,36 @@ class Server:
 
     def _admit(self) -> None:
         """Prefill queued requests into free slots (one at a time)."""
-        for slot in range(self.cfg.batch_slots):
-            if self.active[slot] is not None or not self.queue:
-                continue
-            req = self.queue.pop(0)
-            # prefill THIS slot: run prefill on a batch-1 view then write
-            # the slot's cache lines.  For simplicity and exactness we
-            # re-prefill via a masked full-batch pass: tokens padded.
-            self._prefill_slot(slot, req)
-            self.active[slot] = req
+        free = [slot for slot, r in enumerate(self.active) if r is None]
+        n = min(len(free), len(self.queue))
+        if not n:
+            return
+        with span("serve.admit", admitted=n):
+            for slot in free[:n]:
+                req = self.queue.pop(0)
+                self._prefill_slot(slot, req)
+                self.active[slot] = req
 
     def _prefill_slot(self, slot: int, req: Request) -> None:
+        """Prefill ``req`` into ``slot`` with a masked full-batch pass: the
+        prompt in its row, zeros in the others, on a fresh cache tree;
+        then copy the slot's cache lines into the live tree, so that the
+        other slots keep theirs."""
         plen = len(req.prompt)
         if plen >= self.cfg.max_seq:
             raise ValueError("prompt longer than max_seq")
-        # build a batch with the prompt in `slot` and zeros elsewhere; the
-        # per-slot cache is overwritten only where cache_update writes, so
-        # other slots' K/V lines for [0, plen) would be clobbered.  To keep
-        # slots independent we maintain per-slot caches and re-assemble.
         b = self.cfg.batch_slots
-        toks = np.zeros((b, plen), np.int32)
-        toks[slot] = req.prompt
-        fresh = self.model.init_caches(b, self.cfg.max_seq, dtype=self.dtype)
-        logits, filled = self._prefill_one(self.params, jnp.asarray(toks), fresh)
-        # splice the slot's cache lines into the live cache tree
-        self.caches = _splice_slot(self.caches, filled, slot)
-        nxt = self._sample(logits[slot, -1], req)
+        self.counters["prefill_calls"] += 1
+        self.counters["prefill_tokens_used"] += plen
+        with span("serve.prefill", rid=req.rid, slot=slot, plen=plen, rows=b, used=1):
+            toks = np.zeros((b, plen), np.int32)
+            toks[slot] = req.prompt
+            fresh = self.model.init_caches(b, self.cfg.max_seq, dtype=self.dtype)
+            logits, filled = self._prefill_one(self.params, jnp.asarray(toks), fresh)
+        with span("serve.splice", rid=req.rid, slot=slot):
+            self.caches = _splice_slot(self.caches, filled, slot)
+        with span("serve.sample", rid=req.rid, slots=1):
+            nxt = self._sample(logits[slot, -1], req)
         self.slot_tokens[slot, 0] = nxt
         req.out_tokens.append(int(nxt))
 
@@ -119,23 +145,39 @@ class Server:
 
     def step(self) -> None:
         """One decode tick for all active slots."""
+        t0 = time.perf_counter()
+        tick = self.counters["decode_ticks"]
+        with span("serve.step", tick=tick):
+            self._step(tick)
+        c = self.counters
+        c.update(gc_counters())
+        dt = time.perf_counter() - t0
+        if dt > c["step_max_s"]:
+            c["step_max_s"], c["step_max_tick"] = dt, tick
+
+    def _step(self, tick: int) -> None:
         self._admit()
-        if not any(r is not None for r in self.active):
+        used = sum(r is not None for r in self.active)
+        if not used:
             return
-        logits, self.caches = self._decode(
-            self.params, jnp.asarray(self.slot_tokens), self.caches
-        )
+        b = self.cfg.batch_slots
+        self.counters["decode_ticks"] += 1
+        self.counters["decode_rows_used"] += used
+        with span("serve.decode", tick=tick, rows=b, used=used):
+            logits, self.caches = self._decode(
+                self.params, jnp.asarray(self.slot_tokens), self.caches
+            )
         self.last_logits = logits
-        self.steps += 1
-        for slot, req in enumerate(self.active):
-            if req is None:
-                continue
-            nxt = self._sample(logits[slot, 0], req)
-            req.out_tokens.append(nxt)
-            self.slot_tokens[slot, 0] = nxt
-            if nxt == self.cfg.eos_id or len(req.out_tokens) >= req.max_tokens:
-                req.done = True
-                self.active[slot] = None
+        with span("serve.sample", tick=tick, slots=used):
+            for slot, req in enumerate(self.active):
+                if req is None:
+                    continue
+                nxt = self._sample(logits[slot, 0], req)
+                req.out_tokens.append(nxt)
+                self.slot_tokens[slot, 0] = nxt
+                if nxt == self.cfg.eos_id or len(req.out_tokens) >= req.max_tokens:
+                    req.done = True
+                    self.active[slot] = None
 
     def run_until_done(self, max_ticks: int = 10_000) -> None:
         for _ in range(max_ticks):

@@ -44,3 +44,12 @@ def test_exits_nonzero_without_chip_or_repo(where, tmp_path):
                          capture_output=True, text=True, timeout=300)
     assert out.returncode != 0
     assert '"ok"' not in out.stdout
+
+
+def test_serve_phase_on_cpu(smoke, capsys):
+    # the chip's serve phase, at smoke size: every request answered, one
+    # compile of each step, the final step against a forward pass
+    from repro.configs import get_config
+
+    smoke.serve(get_config("granite-3-2b", smoke=True), seed=0, prompt_len=8)
+    assert "decode ticks" in capsys.readouterr().out

@@ -190,3 +190,14 @@ def test_server_continuous_batching_refills():
     srv.run_until_done()
     assert all(r.done for r in reqs)
     assert all(len(r.out_tokens) == 3 for r in reqs)
+
+
+def test_launch_serve_smoke(monkeypatch):
+    from repro.launch import serve
+
+    monkeypatch.setattr(serve, "enable_compile_cache", lambda: None)
+    out = serve.main(["--arch", "granite-3-2b", "--smoke", "--requests", "3",
+                      "--max-tokens", "3", "--slots", "2", "--max-seq", "32"])
+    assert out["tokens"] == 9
+    # two waves of slots: at least the 3 tokens of each, the first from prefill
+    assert 4 <= out["ticks"] <= 3 * 3
