@@ -5,14 +5,20 @@ prefill fills each request's cache slice; ``decode_step`` advances every
 active slot one token; finished slots (EOS or max_tokens) are freed and
 refilled from the queue — continuous batching at slot granularity.
 
+The jitted step that makes the logits also picks every row's next token
+on the device (``_pick``: argmax where the row's temperature is 0, a
+draw from the tempered softmax elsewhere, with a PRNG key threaded from
+step to step), so the host reads one ``(slots,)`` int32 vector per step
+and passes each slot's pick through ``Server._sample``.
+
 While ``jax.profiler`` traces, every step writes host spans into the
 trace (``serve.step``, ``serve.admit``, ``serve.prefill``,
 ``serve.splice``, ``serve.sample``, ``serve.decode``, and ``gc`` around
 collections; see ``spans.py``), so each device-idle gap can be put down
 to what the host was doing.  ``Server.counters`` counts prefill calls
 and the prompt tokens they served, decode ticks and the slots they
-served, collections and the slowest step, for an operator to read
-between steps.
+served, the reads of the step's picks, collections and the slowest
+step, for an operator to read between steps.
 
 The dry-run lowers the same ``decode_step`` for the production meshes.
 """
@@ -61,35 +67,46 @@ class Server:
         self.dtype = dtype
         self.queue: List[Request] = []
         self.active: List[Optional[Request]] = [None] * cfg.batch_slots
-        self.key = jax.random.key(cfg.seed)
+        self.key = jax.random.key(cfg.seed)  # on the device, threaded through the steps
         # per-slot caches: one cache tree of batch = slots
         self.caches = model.init_caches(cfg.batch_slots, cfg.max_seq, dtype=dtype)
         # traces of each jitted entry, i.e. its compiles: prefill retraces
         # for every new prompt length
         self.compiles: Dict[str, int] = {"prefill": 0, "decode": 0}
 
-        def decode(p, t, c):
+        def decode(p, t, c, temps, key):
             self.compiles["decode"] += 1
-            return model.decode_step(p, t, c)
+            logits, c = model.decode_step(p, t, c)
+            picks, key = _pick(logits[:, -1], temps, key)
+            return logits, picks, c, key
 
-        def prefill(p, t, c):
+        def prefill(p, t, c, temps, key):
             self.compiles["prefill"] += 1
-            return model.prefill(p, t, c)
+            logits, c = model.prefill(p, t, c)
+            picks, key = _pick(logits[:, -1], temps, key)
+            return logits, picks, c, key
 
-        self._decode = jax.jit(decode)
-        self._prefill_one = jax.jit(prefill)
+        def extra():
+            return self._temps, self.key
+
+        self._decode = _Step(decode, extra)
+        self._prefill_one = _Step(prefill, extra)
         self.slot_tokens = np.zeros((cfg.batch_slots, 1), np.int32)
+        # each slot's request temperature; uploaded when a slot is filled
+        self.slot_temps = np.zeros((cfg.batch_slots,), np.float32)
+        self._temps = jnp.asarray(self.slot_temps)
         self.last_logits: Optional[jax.Array] = None  # (slots, 1, V) of the last tick
         # prefill calls (one request each) and the prompt tokens they
         # served, decode ticks and the slots they served, collections by
         # generation, and the slowest step since an operator last set
         # ``step_max_s`` to 0.  Each call and tick computes ``batch_slots``
         # rows, so the padding is slots x calls - calls, and slots x ticks
-        # - ``decode_rows_used``.
+        # - ``decode_rows_used``.  ``sample_reads`` counts the reads of a
+        # step's picks: one per prefill call and per decode tick.
         watch_gc()
         self.counters: Dict[str, float] = {
             "prefill_calls": 0, "prefill_tokens_used": 0,
-            "decode_ticks": 0, "decode_rows_used": 0,
+            "decode_ticks": 0, "decode_rows_used": 0, "sample_reads": 0,
             **gc_counters(), "step_max_s": 0.0, "step_max_tick": -1,
         }
 
@@ -125,23 +142,30 @@ class Server:
             toks = np.zeros((b, plen), np.int32)
             toks[slot] = req.prompt
             fresh = self.model.init_caches(b, self.cfg.max_seq, dtype=self.dtype)
-            logits, filled = self._prefill_one(self.params, jnp.asarray(toks), fresh)
+            # a freed slot keeps its temperature until it is filled again:
+            # its row is computed and its pick ignored either way
+            self.slot_temps[slot] = req.temperature
+            self._temps = jnp.asarray(self.slot_temps)
+            logits, picks, filled, self.key = self._prefill_one(
+                self.params, jnp.asarray(toks), fresh, self._temps, self.key)
         with span("serve.splice", rid=req.rid, slot=slot):
             self.caches = _splice_slot(self.caches, filled, slot)
         with span("serve.sample", rid=req.rid, slots=1):
-            nxt = self._sample(logits[slot, -1], req)
-        self.slot_tokens[slot, 0] = nxt
-        req.out_tokens.append(int(nxt))
+            nxt = self._sample(self._read(picks)[slot], req)
+            self.slot_tokens[slot, 0] = nxt
+            req.out_tokens.append(nxt)
 
     # -- decode ------------------------------------------------------------
 
-    def _sample(self, logits: jax.Array, req: Request) -> int:
-        if req.temperature <= 0.0:
-            return int(jnp.argmax(logits))
-        self.key, sub = jax.random.split(self.key)
-        return int(
-            jax.random.categorical(sub, logits / req.temperature)
-        )
+    def _read(self, picks: jax.Array) -> np.ndarray:
+        """The step's picks on the host: its one device-to-host read."""
+        self.counters["sample_reads"] += 1
+        return np.asarray(picks)
+
+    def _sample(self, pick: int, req: Request) -> int:
+        """The token served to ``req``: its slot's pick of the step.  Every
+        served token passes through here, at admission and at every tick."""
+        return int(pick)
 
     def step(self) -> None:
         """One decode tick for all active slots."""
@@ -164,15 +188,16 @@ class Server:
         self.counters["decode_ticks"] += 1
         self.counters["decode_rows_used"] += used
         with span("serve.decode", tick=tick, rows=b, used=used):
-            logits, self.caches = self._decode(
-                self.params, jnp.asarray(self.slot_tokens), self.caches
+            logits, picks, self.caches, self.key = self._decode(
+                self.params, jnp.asarray(self.slot_tokens), self.caches, self._temps, self.key
             )
         self.last_logits = logits
         with span("serve.sample", tick=tick, slots=used):
+            picks = self._read(picks)
             for slot, req in enumerate(self.active):
                 if req is None:
                     continue
-                nxt = self._sample(logits[slot, 0], req)
+                nxt = self._sample(picks[slot], req)
                 req.out_tokens.append(nxt)
                 self.slot_tokens[slot, 0] = nxt
                 if nxt == self.cfg.eos_id or len(req.out_tokens) >= req.max_tokens:
@@ -184,6 +209,41 @@ class Server:
             if not self.queue and all(r is None for r in self.active):
                 return
             self.step()
+
+
+class _Step:
+    """``jax.jit`` of a step ``fn(params, tokens, caches, temps, key)``.
+    ``lower`` may be given the model's arguments alone, as
+    ``chipbench/attribute.py`` gives them to read the op scopes: it then
+    lowers with ``extra()``, the server's temperatures and key, so that
+    the compiled step is the program that runs."""
+
+    def __init__(self, fn: Callable, extra: Callable[[], Tuple[jax.Array, jax.Array]]):
+        self.jit = jax.jit(fn)
+        self.extra = extra
+
+    def __call__(self, *args):
+        return self.jit(*args)
+
+    def lower(self, *args):
+        return self.jit.lower(*args, *(self.extra() if len(args) == 3 else ()))
+
+
+def _pick(logits: jax.Array, temps: jax.Array, key: jax.Array) -> Tuple[jax.Array, jax.Array]:
+    """Each row's next token from ``logits`` (B, V) and the key for the next
+    step: the argmax where the row's temperature ``temps`` (B,) is 0 or
+    less, else a draw from softmax(logits / temperature).  The draw runs
+    only when some row has a temperature."""
+    key, sub = jax.random.split(key)
+    greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    hot = temps > 0
+
+    def draw():
+        t = jnp.where(hot, temps, 1.0)[:, None]
+        drawn = jax.random.categorical(sub, logits / t, axis=-1).astype(jnp.int32)
+        return jnp.where(hot, drawn, greedy)
+
+    return jax.lax.cond(jnp.any(hot), draw, lambda: greedy), key
 
 
 # base (unstacked) rank of each cache leaf kind; +1 when layer-stacked

@@ -110,6 +110,8 @@ def test_spans_nest_carry_args_and_sum_to_the_counters(small_server, tmp_path):
     # every token served was sampled in one slot of a sample span
     assert sum(s[3]["slots"] for s in by["serve.sample"]) == sum(len(r.out_tokens) for r in reqs)
     assert [s[3]["tick"] for s in by["serve.step"]] == [d[3]["tick"] for d in decodes]
+    # one read of the step's picks per prefill call and per decode tick
+    assert delta["sample_reads"] == delta["prefill_calls"] + delta["decode_ticks"]
 
 
 def test_slowest_step_is_kept_until_reset(small_server):
