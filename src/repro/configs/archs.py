@@ -1,4 +1,5 @@
-"""The 10 assigned architecture configs (exact public hyperparameters).
+"""The 10 assigned architecture configs and granite-4.0-h-small, the
+benchmark's hybrid MoE (exact public hyperparameters).
 
 Each arch provides ``config()`` (full size — dry-run only, never
 materialized) and ``smoke_config()`` (reduced same-family config for CPU
@@ -142,6 +143,25 @@ def jamba_52b() -> ModelConfig:
     )
 
 
+def granite_4_0_h_small() -> ModelConfig:
+    # [hf:ibm-granite/granite-4.0-h-small] 40L d4096: 36 Mamba2 mixers
+    # (128 heads of 64, state 128, 1 group, conv 4, chunk 256) and 4 GQA
+    # mixers (32H kv8 of 128, no position embedding) at 5, 15, 25, 35;
+    # MoE FFN in every layer, 72 experts of 768, top-10, one shared
+    # expert of 1536; granite's multipliers; v100352
+    return ModelConfig(
+        name="granite-4.0-h-small", family="hybrid", n_layers=40, d_model=4096,
+        n_heads=32, n_kv_heads=8, d_ff=768, vocab=100352, head_dim=128,
+        norm_eps=1e-5, use_rope=False,
+        ssm_state=128, ssm_head_dim=64, ssm_expand=2, ssm_groups=1, ssm_chunk=256,
+        hybrid_period=10, hybrid_attn_index=5,
+        n_experts=72, top_k=10, n_shared_experts=1, shared_d_ff=1536,
+        attention_multiplier=0.0078125, embedding_multiplier=12.0,
+        residual_multiplier=0.22, logits_scaling=16.0,
+        vocab_pad_multiple=VPAD, remat="full",
+    )
+
+
 FULL: Dict[str, Callable[[], ModelConfig]] = {
     "granite-20b": granite_20b,
     "granite-3-2b": granite_3_2b,
@@ -153,6 +173,7 @@ FULL: Dict[str, Callable[[], ModelConfig]] = {
     "whisper-base": whisper_base,
     "qwen2-vl-72b": qwen2_vl_72b,
     "jamba-v0.1-52b": jamba_52b,
+    "granite-4.0-h-small": granite_4_0_h_small,
 }
 
 
@@ -178,7 +199,8 @@ def _smoke(full: ModelConfig, **overrides) -> ModelConfig:
     )
     if full.n_experts:
         base.update(n_experts=4, top_k=min(full.top_k, 2),
-                    n_shared_experts=full.n_shared_experts)
+                    n_shared_experts=full.n_shared_experts,
+                    shared_d_ff=96 if full.shared_d_ff else None)
     if full.ssm_state:
         base.update(ssm_state=16, ssm_head_dim=16, ssm_expand=2, ssm_chunk=8)
     if full.attn_kind == "mla":

@@ -43,6 +43,7 @@ class AttnConfig:
     mrope_sections: Optional[Tuple[int, int, int]] = None  # qwen2-vl
     sliding_window: Optional[int] = None
     chunk: int = 512  # kv chunk for the xla flash path
+    scale: Optional[float] = None  # score scale; None: 1/sqrt(head_dim)
 
     @property
     def q_groups(self) -> int:
@@ -117,12 +118,13 @@ def _flash_fwd_scan(q5, kcs, vcs, qpos, skv, causal, window, kv_length, chunk):
     return out5, lse
 
 
-def _flash_core(q, k, v, q_positions, kv_length, causal, window, chunk):
+def _flash_core(q, k, v, q_positions, kv_length, causal, window, chunk, scale):
     """Layout plumbing shared by fwd/bwd. Returns 5-D tensors + meta."""
     b, sq, h, d = q.shape
     skv, kvh = k.shape[1], k.shape[2]
     g = h // kvh
-    scale = 1.0 / math.sqrt(d)
+    if scale is None:
+        scale = 1.0 / math.sqrt(d)
     chunk = min(chunk, skv)
     n_chunks = (skv + chunk - 1) // chunk
     pad = n_chunks * chunk - skv
@@ -136,7 +138,7 @@ def _flash_core(q, k, v, q_positions, kv_length, causal, window, chunk):
     return q5, kcs, vcs, qpos, (b, sq, h, d, skv, kvh, g, scale, chunk, n_chunks, pad)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8))
 def flash_xla(
     q: jax.Array,  # (B, Sq, H, D)
     k: jax.Array,  # (B, Skv, KV, D)
@@ -146,6 +148,7 @@ def flash_xla(
     causal: bool = True,
     window: Optional[int] = None,
     chunk: int = 512,
+    scale: Optional[float] = None,  # score scale; None: 1/sqrt(D)
 ) -> jax.Array:
     """Online-softmax attention scanned over KV chunks (flash-in-XLA).
 
@@ -154,13 +157,13 @@ def flash_xla(
     letting scan-AD stash every chunk's p-matrix — measured 2.1 GiB/layer
     of backward residuals on granite-8b train_4k without it.
     """
-    out, _ = _flash_fwd(q, k, v, q_positions, kv_length, causal, window, chunk)
+    out, _ = _flash_fwd(q, k, v, q_positions, kv_length, causal, window, chunk, scale)
     return out
 
 
-def _flash_fwd(q, k, v, q_positions, kv_length, causal, window, chunk):
+def _flash_fwd(q, k, v, q_positions, kv_length, causal, window, chunk, scale):
     q5, kcs, vcs, qpos, meta = _flash_core(
-        q, k, v, q_positions, kv_length, causal, window, chunk
+        q, k, v, q_positions, kv_length, causal, window, chunk, scale
     )
     b, sq, h, d, skv, kvh, g, scale, chunk_, n_chunks, pad = meta
     out5, lse = _flash_fwd_scan(
@@ -171,10 +174,10 @@ def _flash_fwd(q, k, v, q_positions, kv_length, causal, window, chunk):
     return out, res
 
 
-def _flash_bwd(causal, window, chunk, res, dout):
+def _flash_bwd(causal, window, chunk, scale, res, dout):
     q, k, v, q_positions, kv_length, out5, lse = res
     q5, kcs, vcs, qpos, meta = _flash_core(
-        q, k, v, q_positions, kv_length, causal, window, chunk
+        q, k, v, q_positions, kv_length, causal, window, chunk, scale
     )
     b, sq, h, d, skv, kvh, g, scale, chunk_, n_chunks, pad = meta
     do5 = (
@@ -239,6 +242,7 @@ def attention_ref(
     kv_length: Optional[jax.Array] = None,
     causal: bool = True,
     window: Optional[int] = None,
+    scale: Optional[float] = None,  # score scale; None: 1/sqrt(D)
 ) -> jax.Array:
     """Naive O(S^2) oracle (tests + tiny decode)."""
     b, sq, h, d = q.shape
@@ -247,7 +251,7 @@ def attention_ref(
     k = jnp.repeat(k, g, axis=2)
     v = jnp.repeat(v, g, axis=2)
     s = jnp.einsum("bqhd,bkhd->bhqk", q, k, preferred_element_type=jnp.float32)
-    s = s / math.sqrt(d)
+    s = s / math.sqrt(d) if scale is None else s * scale
     kpos = jnp.arange(k.shape[1])[None, None, None, :]
     qpos = q_positions[:, None, :, None]
     mask = jnp.ones_like(s, dtype=bool)
@@ -359,11 +363,12 @@ def attn_apply(
             out = attention_ref(
                 q, k_all.astype(q.dtype), v_all.astype(q.dtype), qpos1d,
                 kv_length=kv_len, causal=False, window=cfg.sliding_window,
+                scale=cfg.scale,
             )
         else:
             out = flash_xla(
                 q, k_all.astype(q.dtype), v_all.astype(q.dtype), qpos1d,
-                kv_len, cfg.causal, cfg.sliding_window, cfg.chunk,
+                kv_len, cfg.causal, cfg.sliding_window, cfg.chunk, cfg.scale,
             )
     else:
         # NOTE on GQA + TP: when n_kv_heads < model-axis size, flash's
@@ -377,11 +382,13 @@ def attn_apply(
         # model axis for kv=8 archs; left as the documented next lever.
         if use_flash:
             out = flash_xla(
-                q, k, v, qpos1d, None, cfg.causal, cfg.sliding_window, cfg.chunk
+                q, k, v, qpos1d, None, cfg.causal, cfg.sliding_window, cfg.chunk,
+                cfg.scale,
             )
         else:
             out = attention_ref(
-                q, k, v, qpos1d, causal=cfg.causal, window=cfg.sliding_window
+                q, k, v, qpos1d, causal=cfg.causal, window=cfg.sliding_window,
+                scale=cfg.scale,
             )
 
     y = jnp.einsum("bshk,hkd->bsd", out, params["wo"].astype(x.dtype))

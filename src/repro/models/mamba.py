@@ -37,6 +37,7 @@ class SSMConfig:
     chunk: int = 256
     dt_min: float = 0.001
     dt_max: float = 0.1
+    norm_eps: float = 1e-6  # of the gated RMSNorm
 
     @property
     def d_inner(self) -> int:
@@ -283,7 +284,7 @@ def mamba_apply(
 
     # gated RMSNorm (mamba2's norm(y * silu(z)))
     y = y * jax.nn.silu(z.astype(jnp.float32)).astype(x.dtype)
-    y = rmsnorm({"scale": params["norm_scale"]}, y)
+    y = rmsnorm({"scale": params["norm_scale"]}, y, cfg.norm_eps)
     return y @ params["w_out"].astype(x.dtype), new_cache
 
 

@@ -72,6 +72,11 @@ class ModelConfig:
     dense_d_ff: Optional[int] = None  # d_ff of those dense layers
     moe_impl: str = "ragged"
     capacity_factor: float = 1.25
+    # one chip's share of an expert-parallel layer: the router scores all
+    # n_experts, the layer computes the n_experts_held from expert_offset
+    n_experts_held: Optional[int] = None  # None: all
+    expert_offset: int = 0
+    shared_d_ff: Optional[int] = None  # shared expert's hidden; None: d_ff * n_shared
     # SSM / hybrid
     ssm_state: int = 0  # >0 enables mamba mixers
     ssm_head_dim: int = 64
@@ -80,6 +85,11 @@ class ModelConfig:
     ssm_chunk: int = 256
     hybrid_period: int = 0  # jamba: 8 (one attn layer per period)
     hybrid_attn_index: int = 4
+    # granite's multipliers; None applies none (no operation is added)
+    attention_multiplier: Optional[float] = None  # score scale; None: 1/sqrt(head_dim)
+    embedding_multiplier: Optional[float] = None
+    residual_multiplier: Optional[float] = None  # each sublayer's output
+    logits_scaling: Optional[float] = None  # logits divided by it
     # MTP (deepseek)
     mtp: bool = False
     mtp_loss_weight: float = 0.3
@@ -117,6 +127,7 @@ class ModelConfig:
             mrope_sections=self.mrope_sections,
             sliding_window=self.sliding_window,
             chunk=self.attn_chunk,
+            scale=self.attention_multiplier,
         )
 
     def mla_config(self) -> MLAConfig:
@@ -143,6 +154,9 @@ class ModelConfig:
             n_shared_experts=self.n_shared_experts,
             capacity_factor=self.capacity_factor,
             moe_impl=self.moe_impl,
+            n_held=self.n_experts_held,
+            first_expert=self.expert_offset,
+            shared_d_ff=self.shared_d_ff,
         )
 
     def ssm_config(self) -> Optional[SSMConfig]:
@@ -155,6 +169,7 @@ class ModelConfig:
             expand=self.ssm_expand,
             n_groups=self.ssm_groups,
             chunk=self.ssm_chunk,
+            norm_eps=self.norm_eps,
         )
 
     # -- layout --------------------------------------------------------------
@@ -197,22 +212,24 @@ class ModelConfig:
             norm=self.norm,
             norm_eps=self.norm_eps,
             remat=self.remat,
+            residual_multiplier=self.residual_multiplier,
         )
 
     # -- accounting ----------------------------------------------------------
 
     def param_counts(self) -> Tuple[int, int]:
-        """(total, active) parameter counts."""
+        """(total, active) parameter counts of the experts held: a token
+        runs top_k of n_experts, so held * top_k / n_experts of those held
+        on average; shared experts always run."""
         defs = LM(self).param_defs()
         total = P.param_count(defs)
         active = total
         if self.n_experts and self.top_k:
-            scfg = self.stack_config()
-            moe_cfg = scfg.moe
-            per_expert = 3 * self.d_model * self.d_ff
+            moe_cfg = self.moe_config()
+            per_expert = 3 * self.d_model * moe_cfg.d_ff
             n_moe_layers = sum(1 for k in self.layout() if k.ffn == "moe")
-            inactive = n_moe_layers * per_expert * (self.n_experts - self.top_k)
-            active = total - inactive
+            idle = moe_cfg.held * (1 - self.top_k / self.n_experts)
+            active = total - round(n_moe_layers * per_expert * idle)
         return total, active
 
     def model_flops_train(self, batch: int, seq: int) -> float:
@@ -300,6 +317,8 @@ class LM:
 
         with jax.named_scope("embed"):
             x = embed(params["embed"], tokens).astype(cfg.dtype)
+            if cfg.embedding_multiplier is not None:
+                x = x * cfg.embedding_multiplier
             if embeddings is not None:
                 x = x + embeddings.astype(cfg.dtype)
             # the gather from the vocab-sharded embedding leaves x with no
@@ -319,6 +338,8 @@ class LM:
                 logits = (x @ params["unembed"]["w_out"].astype(x.dtype)).astype(
                     jnp.float32
                 )
+            if cfg.logits_scaling is not None:
+                logits = logits / cfg.logits_scaling
         return logits, new_caches, aux
 
     # -- loss --------------------------------------------------------------------

@@ -17,6 +17,16 @@ Dispatch strategies (config ``moe_impl``):
 
 Experts shard over the logical ``expert`` axis (-> mesh model axis) for
 EP; the router is replicated.
+
+A layer may hold a share of the experts (``n_held`` from ``first_expert``),
+as one chip of an expert-parallel deployment does: the router still
+scores all ``n_experts`` and keeps the full top-k weights, and the layer
+returns the part of the result that its own experts give (the shared
+expert included).  Only the ragged path takes that cut; the others raise.
+
+On the ragged path a profile splits the layer's work into ``route``
+(router, top-k, the sort and gather of the dispatch), ``experts`` (the
+grouped matmuls and the combine) and ``shared`` (the always-on expert).
 """
 
 from __future__ import annotations
@@ -41,18 +51,29 @@ class MoEConfig:
     moe_impl: str = "ragged"  # 'ragged' | 'capacity'
     router_noise: float = 0.0
     aux_loss_weight: float = 0.01
+    n_held: Optional[int] = None  # experts held here; None: all
+    first_expert: int = 0  # id of the first held expert
+    shared_d_ff: Optional[int] = None  # shared hidden; None: d_ff * n_shared_experts
+
+    @property
+    def held(self) -> int:
+        return self.n_experts if self.n_held is None else self.n_held
+
+    @property
+    def shared_width(self) -> int:
+        return self.shared_d_ff or self.d_ff * self.n_shared_experts
 
 
 def moe_defs(cfg: MoEConfig) -> Dict[str, ParamDef]:
     e, d, f = cfg.n_experts, cfg.d_model, cfg.d_ff
     defs = {
         "router": ParamDef((d, e), ("embed", None), scale=0.1),
-        "w_gate": ParamDef((e, d, f), ("expert", "embed", "mlp")),
-        "w_up": ParamDef((e, d, f), ("expert", "embed", "mlp")),
-        "w_down": ParamDef((e, f, d), ("expert", "mlp", "embed"), init="out_proj"),
+        "w_gate": ParamDef((cfg.held, d, f), ("expert", "embed", "mlp")),
+        "w_up": ParamDef((cfg.held, d, f), ("expert", "embed", "mlp")),
+        "w_down": ParamDef((cfg.held, f, d), ("expert", "mlp", "embed"), init="out_proj"),
     }
     if cfg.n_shared_experts:
-        fs = f * cfg.n_shared_experts
+        fs = cfg.shared_width
         defs.update(
             {
                 "shared_w_gate": ParamDef((d, fs), ("embed", "mlp")),
@@ -97,26 +118,44 @@ def moe_apply_ragged(
     b, s, d = x.shape
     x2d = x.reshape(b * s, d)
     t = b * s
-    top_e, top_w, aux = _router(params, x2d, cfg, rng)
+    cut = cfg.held < cfg.n_experts
+    with jax.named_scope("route"):
+        top_e, top_w, aux = _router(params, x2d, cfg, rng)
 
-    # flatten (token, slot) pairs and sort by expert id
-    flat_e = top_e.reshape(-1)  # (T*k,)
-    token_idx = jnp.repeat(jnp.arange(t), cfg.top_k)
-    order = jnp.argsort(flat_e)  # stable
-    sorted_tokens = token_idx[order]
-    xs = x2d[sorted_tokens]  # (T*k, d) gather
-    group_sizes = jnp.bincount(flat_e, length=cfg.n_experts).astype(jnp.int32)
+        # flatten (token, slot) pairs and sort by expert id
+        flat_e = top_e.reshape(-1)  # (T*k,)
+        if cut:  # held experts by local id; the others sort past them
+            flat_e = flat_e - cfg.first_expert
+            mine = (flat_e >= 0) & (flat_e < cfg.held)
+            flat_e = jnp.where(mine, flat_e, cfg.held)
+        token_idx = jnp.repeat(jnp.arange(t), cfg.top_k)
+        order = jnp.argsort(flat_e)  # stable
+        sorted_tokens = token_idx[order]
+        xs = x2d[sorted_tokens]  # (T*k, d) gather
+        # rows past the held groups' sum belong to no group
+        group_sizes = jnp.bincount(flat_e, length=cfg.held).astype(jnp.int32)
 
-    ys = _expert_ffn_ragged(params, xs, group_sizes, x.dtype)  # (T*k, d)
+    with jax.named_scope("experts"):
+        ys = _expert_ffn_ragged(params, xs, group_sizes, x.dtype)  # (T*k, d)
 
-    # unsort + weighted combine
-    inv = jnp.zeros_like(order).at[order].set(jnp.arange(order.shape[0]))
-    ys = ys[inv].reshape(t, cfg.top_k, d)
-    y = jnp.einsum("tkd,tk->td", ys, top_w.astype(ys.dtype))
-    y = y.astype(x.dtype)
+        # unsort + weighted combine
+        inv = jnp.zeros_like(order).at[order].set(jnp.arange(order.shape[0]))
+        ys = ys[inv].reshape(t, cfg.top_k, d)
+        if cut:  # an absent expert's assignment adds nothing
+            ys = jnp.where(mine.reshape(t, cfg.top_k, 1), ys, 0)
+        y = jnp.einsum("tkd,tk->td", ys, top_w.astype(ys.dtype))
+        y = y.astype(x.dtype)
     if cfg.n_shared_experts:
-        y = y + _shared_ffn(params, x2d)
+        with jax.named_scope("shared"):
+            y = y + _shared_ffn(params, x2d)
     return y.reshape(b, s, d), aux
+
+
+def _require_all_held(cfg: MoEConfig, path: str) -> None:
+    if cfg.held < cfg.n_experts:
+        raise NotImplementedError(
+            f"the {path} dispatch holds every expert; a share of "
+            f"{cfg.held} of {cfg.n_experts} runs on the ragged path only")
 
 
 def moe_apply_capacity(
@@ -135,6 +174,7 @@ def moe_apply_capacity(
     layout and the model-sharded E layout: the classic 2x all-to-all of
     expert parallelism, inserted by GSPMD.
     """
+    _require_all_held(cfg, "capacity")
     b, s, d = x.shape
     e, k = cfg.n_experts, cfg.top_k
     x2d = x.reshape(b * s, d)
@@ -218,6 +258,7 @@ def moe_apply_ep(
 
     from repro.parallel.context import active_mesh, active_rules
 
+    _require_all_held(cfg, "expert-parallel")
     mesh = active_mesh()
     rules = active_rules()
     if (
@@ -341,8 +382,8 @@ def moe_apply(
 def moe_ref(
     params: Dict[str, jax.Array], x: jax.Array, cfg: MoEConfig
 ) -> Tuple[jax.Array, jax.Array]:
-    """Dense oracle: run every token through every expert, weight by the
-    full top-k gate. O(E) compute — tests only."""
+    """Dense oracle: run every token through every held expert, weight by
+    the full top-k gate. O(E) compute — tests only."""
     b, s, d = x.shape
     x2d = x.reshape(b * s, d)
     top_e, top_w, aux = _router(params, x2d, cfg)
@@ -353,7 +394,8 @@ def moe_ref(
     w_full = jnp.zeros((b * s, cfg.n_experts), x.dtype)
     for k in range(cfg.top_k):
         w_full = w_full.at[jnp.arange(b * s), top_e[:, k]].add(top_w[:, k])
-    y = jnp.einsum("ted,te->td", eo, w_full)
+    w_held = w_full[:, cfg.first_expert:cfg.first_expert + cfg.held]
+    y = jnp.einsum("ted,te->td", eo, w_held)
     if cfg.n_shared_experts:
         y = y + _shared_ffn(params, x2d)
     return y.reshape(b, s, d), aux

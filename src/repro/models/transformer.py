@@ -59,6 +59,8 @@ class StackConfig:
     norm: str = "rmsnorm"  # 'rmsnorm' | 'layernorm'
     norm_eps: float = 1e-6
     remat: str = "none"  # 'none' | 'full'
+    # granite's scale of each sublayer's output before the residual add
+    residual_multiplier: Optional[float] = None
     # optional activation-sharding constraint applied to the residual
     # stream at every block boundary (the launcher installs e.g. a
     # sequence-parallel (batch, seq-over-model, none) constraint here)
@@ -146,6 +148,12 @@ def block_defs(cfg: StackConfig, kind: BlockKind) -> Dict[str, Any]:
 MIXER_SCOPES = {"attn": "attn", "mla": "mla", "mamba": "ssm"}
 
 
+def _residual(cfg: StackConfig, x, y):
+    if cfg.residual_multiplier is None:
+        return x + y
+    return x + y * cfg.residual_multiplier
+
+
 def block_apply(
     params: Dict[str, Any],
     x: jax.Array,
@@ -169,21 +177,21 @@ def block_apply(
             y, new_cache = attn_mod.mla_apply(params["mla"], h, pos1d, cfg.mla, cache)
         else:  # mamba
             y, new_cache = mamba_mod.mamba_apply(params["mamba"], h, cfg.ssm, cache)
-        x = x + y
+        x = _residual(cfg, x, y)
     if kind.ffn == "mlp":
         with jax.named_scope("mlp"):
             h = _norm(cfg, params["norm_ffn"], x)
             if cfg.mlp_kind == "gelu":
                 from .layers import gelu_mlp
 
-                x = x + gelu_mlp(params["mlp"], h)
+                x = _residual(cfg, x, gelu_mlp(params["mlp"], h))
             else:
-                x = x + swiglu(params["mlp"], h)
+                x = _residual(cfg, x, swiglu(params["mlp"], h))
     elif kind.ffn == "moe":
         with jax.named_scope("moe"):
             h = _norm(cfg, params["norm_ffn"], x)
             y, moe_aux = moe_mod.moe_apply(params["moe"], h, cfg.moe)
-            x = x + y
+            x = _residual(cfg, x, y)
             aux = aux + moe_aux
     if cfg.act_constraint is not None:
         # constrain the OUTPUT too: the scan carry is what AD stashes per

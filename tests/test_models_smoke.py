@@ -17,7 +17,7 @@ from repro.optim import adamw, constant
 from repro.runtime import TrainConfig, build_train_step, init_state
 
 
-@pytest.mark.parametrize("arch_id", ARCH_IDS)
+@pytest.mark.parametrize("arch_id", ARCH_IDS + ["granite-4.0-h-small"])
 def test_smoke_forward_and_train_step(arch_id):
     cfg = get_config(arch_id, smoke=True)
     model = build_model(cfg)
@@ -58,7 +58,7 @@ def test_smoke_forward_and_train_step(arch_id):
 
 
 @pytest.mark.parametrize("arch_id", ["granite-8b", "mamba2-2.7b", "jamba-v0.1-52b",
-                                     "deepseek-v3-671b", "whisper-base"])
+                                     "deepseek-v3-671b", "whisper-base", "granite-4.0-h-small"])
 def test_smoke_decode(arch_id):
     """Prefill + one decode step on the reduced config."""
     cfg = get_config(arch_id, smoke=True)
@@ -98,6 +98,13 @@ def test_layouts_match_assignment():
     mb = get_config("mamba2-2.7b")
     assert all(k.mixer == "mamba" and k.ffn == "none" for k in mb.layout())
 
+    gh = get_config("granite-4.0-h-small")
+    lo = gh.layout()
+    assert len(lo) == 40
+    assert [i for i, k in enumerate(lo) if k.mixer == "attn"] == [5, 15, 25, 35]
+    assert all(k.mixer == "mamba" for i, k in enumerate(lo) if i % 10 != 5)
+    assert all(k.ffn == "moe" for k in lo)  # MoE in every layer
+
 
 def test_param_counts_match_public_sizes():
     expect = {
@@ -113,6 +120,8 @@ def test_param_counts_match_public_sizes():
 
 
 def test_active_params_moe():
+    total, active = get_config("granite-4.0-h-small").param_counts()
+    assert 31e9 < total < 33e9 and 8.5e9 < active < 9.5e9  # 32B total, 9B active
     total, active = get_config("deepseek-v3-671b").param_counts()
     assert 35e9 < active < 40e9  # paper: 37B activated
     total, active = get_config("llama4-scout-17b-a16e").param_counts()

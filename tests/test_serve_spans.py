@@ -141,6 +141,7 @@ SCOPES = {  # arch: scopes its steps must carry
     "granite-3-2b": ("embed", "stack", "attn", "mlp", "unembed"),
     "mamba2-2.7b": ("embed", "stack", "ssm", "ssm/scan", "unembed"),
     "deepseek-v3-671b": ("mla", "moe"),
+    "granite-4.0-h-small": ("attn", "ssm", "moe", "moe/route", "moe/experts", "moe/shared"),
 }
 
 
