@@ -410,8 +410,9 @@ class LM:
         params: Dict[str, Any],
         tokens: jax.Array,  # (B, S)
         caches: Dict[str, Any],
+        last_only: bool = False,  # logits (B, 1, V) of the final position only
     ) -> Tuple[jax.Array, Dict[str, Any]]:
-        logits, new_caches, _ = self.apply(params, tokens, caches=caches)
+        logits, new_caches, _ = self.apply(params, tokens, caches=caches, last_only=last_only)
         return logits, new_caches
 
 

@@ -1,15 +1,18 @@
-"""Serving runtime: batched prefill + decode with KV-cache management.
+"""Serving runtime: per-request prefill + batched decode with KV-cache management.
 
 ``Server`` packs concurrent requests into a fixed-batch decode loop:
-prefill fills each request's cache slice; ``decode_step`` advances every
-active slot one token; finished slots (EOS or max_tokens) are freed and
-refilled from the queue — continuous batching at slot granularity.
+each admitted request is prefilled alone, as one row on a zero one-row
+cache tree, with the logits of its last position only, and the filled
+row is written into its slot of the live cache in place; ``decode_step``
+advances every active slot one token; finished slots (EOS or
+max_tokens) are freed and refilled from the queue — continuous batching
+at slot granularity.
 
 The jitted step that makes the logits also picks every row's next token
 on the device (``_pick``: argmax where the row's temperature is 0, a
 draw from the tempered softmax elsewhere, with a PRNG key threaded from
-step to step), so the host reads one ``(slots,)`` int32 vector per step
-and passes each slot's pick through ``Server._sample``.
+step to step), so the host reads one int32 vector per step and passes
+each slot's pick through ``Server._sample``.
 
 While ``jax.profiler`` traces, every step writes host spans into the
 trace (``serve.step``, ``serve.admit``, ``serve.prefill``,
@@ -26,6 +29,7 @@ The dry-run lowers the same ``decode_step`` for the production meshes.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import time
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
@@ -64,12 +68,14 @@ class Server:
         self.model = model
         self.params = params
         self.cfg = cfg
-        self.dtype = dtype
         self.queue: List[Request] = []
         self.active: List[Optional[Request]] = [None] * cfg.batch_slots
         self.key = jax.random.key(cfg.seed)  # on the device, threaded through the steps
         # per-slot caches: one cache tree of batch = slots
         self.caches = model.init_caches(cfg.batch_slots, cfg.max_seq, dtype=dtype)
+        # what every admission prefills into: one zero row, never donated,
+        # as a prefill from position 0 with no prior state needs
+        self._row_caches = model.init_caches(1, cfg.max_seq, dtype=dtype)
         # traces of each jitted entry, i.e. its compiles: prefill retraces
         # for every new prompt length
         self.compiles: Dict[str, int] = {"prefill": 0, "decode": 0}
@@ -82,7 +88,7 @@ class Server:
 
         def prefill(p, t, c, temps, key):
             self.compiles["prefill"] += 1
-            logits, c = model.prefill(p, t, c)
+            logits, c = model.prefill(p, t, c, last_only=True)
             picks, key = _pick(logits[:, -1], temps, key)
             return logits, picks, c, key
 
@@ -99,10 +105,11 @@ class Server:
         # prefill calls (one request each) and the prompt tokens they
         # served, decode ticks and the slots they served, collections by
         # generation, and the slowest step since an operator last set
-        # ``step_max_s`` to 0.  Each call and tick computes ``batch_slots``
-        # rows, so the padding is slots x calls - calls, and slots x ticks
-        # - ``decode_rows_used``.  ``sample_reads`` counts the reads of a
-        # step's picks: one per prefill call and per decode tick.
+        # ``step_max_s`` to 0.  A prefill call computes its request's row
+        # alone, with no padding; a tick computes ``batch_slots`` rows, so
+        # its padding is slots x ticks - ``decode_rows_used``.
+        # ``sample_reads`` counts the reads of a step's picks: one per
+        # prefill call and per decode tick.
         watch_gc()
         self.counters: Dict[str, float] = {
             "prefill_calls": 0, "prefill_tokens_used": 0,
@@ -126,32 +133,29 @@ class Server:
                 req = self.queue.pop(0)
                 self._prefill_slot(slot, req)
                 self.active[slot] = req
+            # a freed slot keeps its temperature until it is filled again:
+            # decode computes its row and ignores its pick either way
+            self._temps = jnp.asarray(self.slot_temps)
 
     def _prefill_slot(self, slot: int, req: Request) -> None:
-        """Prefill ``req`` into ``slot`` with a masked full-batch pass: the
-        prompt in its row, zeros in the others, on a fresh cache tree;
-        then copy the slot's cache lines into the live tree, so that the
+        """Prefill ``req`` alone: its prompt as one row on the zero one-row
+        cache tree, the logits of its last position only; then write the
+        filled row into ``slot`` of the live tree in place, so that the
         other slots keep theirs."""
         plen = len(req.prompt)
         if plen >= self.cfg.max_seq:
             raise ValueError("prompt longer than max_seq")
-        b = self.cfg.batch_slots
         self.counters["prefill_calls"] += 1
         self.counters["prefill_tokens_used"] += plen
-        with span("serve.prefill", rid=req.rid, slot=slot, plen=plen, rows=b, used=1):
-            toks = np.zeros((b, plen), np.int32)
-            toks[slot] = req.prompt
-            fresh = self.model.init_caches(b, self.cfg.max_seq, dtype=self.dtype)
-            # a freed slot keeps its temperature until it is filled again:
-            # its row is computed and its pick ignored either way
+        with span("serve.prefill", rid=req.rid, slot=slot, plen=plen, rows=1, used=1):
             self.slot_temps[slot] = req.temperature
-            self._temps = jnp.asarray(self.slot_temps)
-            logits, picks, filled, self.key = self._prefill_one(
-                self.params, jnp.asarray(toks), fresh, self._temps, self.key)
+            logits, picks, row, self.key = self._prefill_one(
+                self.params, jnp.asarray(req.prompt[None], jnp.int32), self._row_caches,
+                jnp.asarray(self.slot_temps[slot:slot + 1]), self.key)
         with span("serve.splice", rid=req.rid, slot=slot):
-            self.caches = _splice_slot(self.caches, filled, slot)
+            self.caches = _splice_slot(self.caches, row, slot)
         with span("serve.sample", rid=req.rid, slots=1):
-            nxt = self._sample(self._read(picks)[slot], req)
+            nxt = self._sample(self._read(picks)[0], req)
             self.slot_tokens[slot, 0] = nxt
             req.out_tokens.append(nxt)
 
@@ -251,32 +255,30 @@ _CACHE_BASE_RANK = {"k": 4, "v": 4, "c_kv": 3, "k_rope": 3, "conv": 3, "ssm": 4,
                     "length": 0}
 
 
-def _splice_slot(live: PyTree, fresh: PyTree, slot: int) -> PyTree:
-    """Copy slot ``slot``'s batch line from ``fresh`` into ``live``.
+@functools.partial(jax.jit, donate_argnums=0)
+def _splice_slot(live: PyTree, row: PyTree, slot: jax.Array) -> PyTree:
+    """Write the one-row cache tree ``row`` into batch line ``slot`` of
+    ``live``, in place: ``live`` is donated, and ``slot`` is traced, so
+    one program serves every slot.
 
     Leaf kind is identified by its dict key; the batch dim is axis 0 for
     plain caches and axis 1 when stacked under a layer dim (rank is
-    base+1).  The scalar ``length`` adopts the max: slots shorter than
-    the max are correct because their cache lines past their own fill
-    hold zero K/V that only their own decode steps overwrite, and
-    positions mask attention per slot.
+    base+1).  The scalar ``length`` adopts the max, and every slot's
+    next token is written and positioned at it: a slot that holds fewer
+    positions than the max attends over the zero lines between, so a
+    slot is served exactly when its prompt is as long as the live cache,
+    as in a wave of equal prompts on a fresh tree.
     """
-    flat_live, treedef = jax.tree_util.tree_flatten_with_path(live)
-    flat_fresh = jax.tree_util.tree_flatten_with_path(fresh)[0]
-    out = []
-    for (path, a), (_, b) in zip(flat_live, flat_fresh):
+
+    def put(path, a, b):
         name = str(getattr(path[-1], "key", ""))
         base = _CACHE_BASE_RANK.get(name)
         if base is None:
-            out.append(a)
-            continue
+            return a
         if name == "length":
-            out.append(jnp.maximum(a, b))
-            continue
-        if a.ndim == base:  # plain: (B, ...)
-            out.append(a.at[slot].set(b[slot]))
-        else:  # stacked: (L, B, ...)
-            out.append(a.at[:, slot].set(b[:, slot]))
-    return jax.tree_util.tree_unflatten(
-        jax.tree_util.tree_structure(live), [x for x in out]
-    )
+            return jnp.maximum(a, b)
+        at = [0] * a.ndim
+        at[0 if a.ndim == base else 1] = slot  # plain (B, ...) or stacked (L, B, ...)
+        return jax.lax.dynamic_update_slice(a, b, at)
+
+    return jax.tree_util.tree_map_with_path(put, live, row)

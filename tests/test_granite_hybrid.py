@@ -93,11 +93,11 @@ def test_server_prefill_then_decode_matches_the_reference():
     srv = Server(model, params, ServeConfig(batch_slots=3, max_seq=40), dtype=jnp.float32)
     seen = {0: [], 1: [], 2: []}  # slot -> the logits that chose its tokens
     prefill = srv._prefill_one
+    admitted = iter(seen)  # free slots fill in order: request i in slot i
 
     def recording_prefill(p, toks, caches, temps, key):
-        out = prefill(p, toks, caches, temps, key)
-        slot = int(np.flatnonzero(np.asarray(toks).any(axis=1))[0])  # the prompt's row
-        seen[slot].append(np.asarray(out[0][slot, -1]))
+        out = prefill(p, toks, caches, temps, key)  # the request's own row
+        seen[next(admitted)].append(np.asarray(out[0][0, -1]))
         return out
 
     srv._prefill_one = recording_prefill
@@ -109,7 +109,7 @@ def test_server_prefill_then_decode_matches_the_reference():
         srv.submit(r)
     while not all(r.done for r in reqs):
         srv.step()
-        for slot, r in enumerate(reqs):  # free slots fill in order: request i in slot i
+        for slot, r in enumerate(reqs):
             if len(seen[slot]) < len(r.out_tokens):  # this step's decode tick served it
                 seen[slot].append(np.asarray(srv.last_logits[slot, 0]))
     ref = family.logits_fn(cell.config)

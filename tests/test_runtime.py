@@ -10,6 +10,7 @@ import numpy as np
 import pytest
 
 from repro.checkpoint import CheckpointManager
+from repro.configs import get_config
 from repro.data import DataConfig, SyntheticSource, TokenPipeline
 from repro.models import ModelConfig, build_model
 from repro.optim import adamw, constant, cosine_warmup
@@ -27,6 +28,7 @@ from repro.runtime import (
     retry,
     run,
 )
+from repro.runtime import serve as serve_mod
 
 
 def _tiny():
@@ -231,6 +233,110 @@ def test_server_continuous_batching_refills():
     srv.run_until_done()
     assert all(r.done for r in reqs)
     assert all(len(r.out_tokens) == 3 for r in reqs)
+
+
+SERVED = ["granite-3-2b", "mamba2-2.7b", "granite-4.0-h-small", "deepseek-v3-671b"]
+
+
+def _full_batch_splice(live, fresh, slot):
+    """The admission before one-row prefill: the slot's batch line of a
+    full-batch tree copied into the live tree, the max of the lengths."""
+    def put(path, a, b):
+        name = path[-1].key
+        if name == "length":
+            return np.maximum(a, b)
+        a = np.array(a)
+        if a.ndim == serve_mod._CACHE_BASE_RANK[name]:
+            a[slot] = b[slot]
+        else:
+            a[:, slot] = b[:, slot]
+        return a
+
+    return jax.tree_util.tree_map_with_path(put, live, fresh)
+
+
+@pytest.mark.parametrize("arch", SERVED)
+def test_server_one_row_admission(arch):
+    """Each admission prefills its request alone and writes the row into
+    its slot in place: the live cache after it equals the full-batch
+    prefill and splice leaf by leaf, the splice compiles once for every
+    slot and donates the live tree, and the tokens served equal the
+    direct prefill and decode path.  Slots fill in the order 0, 1, 2,
+    then refill as requests finish: 1, then 0.  Each refill's prompt is as
+    long as the live cache, the one refill that the scalar ``length``
+    serves exactly (the harness gives each wave fresh caches)."""
+    cfg = get_config(arch, smoke=True)
+    m = build_model(cfg)
+    params = m.init(jax.random.key(0))
+    slots, max_seq, plen = 3, 26, 5
+    srv = Server(m, params, ServeConfig(batch_slots=slots, max_seq=max_seq),
+                 dtype=jnp.float32)
+    rng = np.random.default_rng(1)
+    # the first wave frees slot 1, then slot 0; refills come in as their
+    # slots free, each prompt as long as the live cache by then
+    lens = [(plen, 3), (plen, 2), (plen, 4), (plen + 1, 3), (plen + 2, 2)]
+    reqs = [Request(rid=i, prompt=rng.integers(1, cfg.vocab, n).astype(np.int32),
+                    max_tokens=k) for i, (n, k) in enumerate(lens)]
+    full_prefill = jax.jit(m.prefill)
+    admitted = []
+    spliced = serve_mod._splice_slot._cache_size()
+    admit_one = srv._prefill_slot
+
+    def checked(slot, req):
+        before = jax.tree.map(np.array, srv.caches)
+        old_leaves = jax.tree.leaves(srv.caches)
+        toks = np.zeros((slots, len(req.prompt)), np.int32)
+        toks[slot] = req.prompt
+        _, fresh = full_prefill(params, jnp.asarray(toks),
+                                m.init_caches(slots, max_seq, dtype=jnp.float32))
+        want = _full_batch_splice(before, jax.tree.map(np.asarray, fresh), slot)
+        admit_one(slot, req)
+        assert all(leaf.is_deleted() for leaf in old_leaves)  # donated, written in place
+        got = jax.tree_util.tree_flatten_with_path(srv.caches)[0]
+        for (path, g), w in zip(got, jax.tree.leaves(want)):
+            np.testing.assert_allclose(np.asarray(g), w, rtol=1e-5, atol=1e-6,
+                                       err_msg=jax.tree_util.keystr(path))
+        admitted.append(slot)
+
+    srv._prefill_slot = checked
+    first, refills = reqs[:slots], reqs[slots:]
+    for r in first:
+        srv.submit(r)
+    while refills or not all(r.done for r in reqs):
+        srv.step()
+        if refills and any(a is None for a in srv.active):
+            srv.submit(refills.pop(0))
+    assert admitted == [0, 1, 2, 1, 0]
+    assert serve_mod._splice_slot._cache_size() == spliced + 1  # one program, every slot
+
+    decode_step = jax.jit(m.decode_step)
+
+    def direct(prompt, n):  # greedy, one row, argmax on the host
+        caches = m.init_caches(1, max_seq, dtype=jnp.float32)
+        lg, caches = full_prefill(params, jnp.asarray(prompt)[None], caches)
+        toks = [int(jnp.argmax(lg[0, -1]))]
+        while len(toks) < n:
+            lg, caches = decode_step(params, jnp.asarray([[toks[-1]]]), caches)
+            toks.append(int(jnp.argmax(lg[0, 0])))
+        return toks
+
+    assert [r.out_tokens for r in reqs] == [direct(r.prompt, r.max_tokens) for r in reqs]
+    assert srv.compiles == {"prefill": len({n for n, _ in lens}), "decode": 1}
+
+
+@pytest.mark.parametrize("arch", SERVED)
+def test_prefill_last_only_is_the_last_position(arch):
+    cfg = get_config(arch, smoke=True)
+    m = build_model(cfg)
+    params = m.init(jax.random.key(0))
+    tokens = jax.random.randint(jax.random.key(1), (2, 7), 0, cfg.vocab)
+    caches = m.init_caches(2, 16, dtype=jnp.float32)
+    full, full_caches = m.prefill(params, tokens, caches)
+    last, last_caches = m.prefill(params, tokens, caches, last_only=True)
+    assert last.shape == (2, 1, cfg.padded_vocab)
+    np.testing.assert_allclose(np.asarray(last), np.asarray(full[:, -1:]), rtol=1e-5, atol=1e-6)
+    for a, b in zip(jax.tree.leaves(full_caches), jax.tree.leaves(last_caches)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
 def test_launch_serve_smoke(monkeypatch):

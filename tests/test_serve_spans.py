@@ -87,7 +87,7 @@ def test_spans_nest_carry_args_and_sum_to_the_counters(small_server, tmp_path):
 
     prefills = by["serve.prefill"]
     assert [p[3]["rid"] for p in prefills] == [r.rid for r in reqs]
-    assert all(p[3]["rows"] == 3 and p[3]["used"] == 1 for p in prefills)
+    assert all(p[3]["rows"] == 1 and p[3]["used"] == 1 for p in prefills)
     assert [p[3]["plen"] for p in prefills] == [len(r.prompt) for r in reqs]
     splices = by["serve.splice"]
     assert [(s[3]["rid"], s[3]["slot"]) for s in splices] == \
