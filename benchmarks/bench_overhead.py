@@ -62,9 +62,8 @@ from typing import List, Optional, Tuple
 
 import numpy as np
 
-from repro.core import collect
 from repro.core._reference import ReferenceAnalyzer, collect_reference
-from repro.core.collector import ShardedCollector, analyze, sourced_spec
+from repro.core.collector import ShardedCollector, analyze, collect, sourced_spec
 from repro.core.heatmap import Analyzer
 from repro.core.session import heatmaps_equal
 from repro.core.trace import GridSampler

@@ -17,7 +17,8 @@ from typing import List, Tuple
 
 import numpy as np
 
-from repro.core import analyze, detect_all
+from repro.core.collector import analyze
+from repro.core.patterns import detect_all
 from repro.core.trace import GridSampler
 from repro.kernels.gemm import gemm_v00_spec, gemm_v01_spec, gemm_v02_spec
 from repro.kernels.gramschm import k3_naive_spec, k3_opt_spec

@@ -21,13 +21,8 @@ from repro.checkpoint import CheckpointManager
 from repro.data import DataConfig, SyntheticSource, TokenPipeline
 from repro.models import ModelConfig, build_model
 from repro.optim import adamw, cosine_warmup
-from repro.runtime import (
-    StragglerMonitor,
-    TrainConfig,
-    build_train_step,
-    init_state,
-    run,
-)
+from repro.fault import StragglerMonitor
+from repro.runtime.train_loop import TrainConfig, build_train_step, init_state, run
 
 
 def main() -> None:

@@ -784,7 +784,7 @@ def _cmd_model(args: argparse.Namespace) -> int:
     from repro.core.session import SessionError
 
     if args.list:
-        from repro.models.registry import MODELS
+        from repro.core.model_kernels import MODELS
 
         for name, entry in MODELS.items():
             cfg = entry.config
@@ -802,7 +802,7 @@ def _cmd_model(args: argparse.Namespace) -> int:
         return 2
     import signal
 
-    from repro.runtime.fault import Preempted, PreemptionHandler
+    from repro.fault import Preempted, PreemptionHandler
 
     sampler = _parse_sampler(args.sampler)
     plan = _parse_fault_plan(args.inject_faults)
@@ -1035,7 +1035,7 @@ def _cmd_tune(args: argparse.Namespace) -> int:
             import signal
 
             from repro.core.tuner import tune_all
-            from repro.runtime.fault import Preempted, PreemptionHandler
+            from repro.fault import Preempted, PreemptionHandler
 
             run = {
                 "format": "cuthermo-tune-journal",

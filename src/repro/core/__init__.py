@@ -5,84 +5,7 @@ Levels:
   2. ``collector.drain_dynamic`` — in-kernel trace buffers for gathers
   3. ``hlo_thermo`` — distributed (compiled-HLO) heat + collective bytes
 
-Public API lives in ``repro.core.api`` (also re-exported here).
+The public API lives in ``repro.core.api``.  This package imports none
+of its submodules, so importing one (``repro.core.spec`` from a kernel
+module, say) loads only what that submodule needs.
 """
-
-from . import advisor, api, collector, diff as diff_mod, heatmap, hlo_cost
-from . import hlo_thermo, patterns, render, roofline, session, tiles, trace
-from . import tuner
-from .diff import HeatmapDiff, diff
-from .tuner import Candidate, TuneResult, TuneStep, tune
-from .api import (
-    actions,
-    advise,
-    detect_all,
-    format_report,
-    heatmap as heatmap_of,
-    patterns as patterns_of,
-    report,
-)
-from .collector import (
-    KernelSpec,
-    OperandSpec,
-    ScratchSpec,
-    ShardedCollector,
-    analyze,
-    analyze_sharded,
-    collect,
-    sourced_spec,
-)
-from .heatmap import Analyzer, Heatmap, HeatKeys
-from .trace import ShardInfo
-from .patterns import PatternReport
-from .session import Iteration, ProfileSession, SessionDiff, SessionError
-from .trace import GridSampler, KernelWhitelist, TraceBuffer
-
-__all__ = [
-    "Analyzer",
-    "Candidate",
-    "GridSampler",
-    "HeatKeys",
-    "Heatmap",
-    "HeatmapDiff",
-    "Iteration",
-    "ProfileSession",
-    "SessionDiff",
-    "SessionError",
-    "ShardInfo",
-    "ShardedCollector",
-    "TuneResult",
-    "TuneStep",
-    "diff",
-    "hlo_cost",
-    "KernelSpec",
-    "KernelWhitelist",
-    "OperandSpec",
-    "PatternReport",
-    "ScratchSpec",
-    "TraceBuffer",
-    "actions",
-    "advise",
-    "advisor",
-    "analyze",
-    "analyze_sharded",
-    "sourced_spec",
-    "api",
-    "collect",
-    "collector",
-    "detect_all",
-    "format_report",
-    "heatmap",
-    "heatmap_of",
-    "hlo_thermo",
-    "patterns",
-    "patterns_of",
-    "render",
-    "report",
-    "roofline",
-    "session",
-    "tiles",
-    "trace",
-    "tune",
-    "tuner",
-]

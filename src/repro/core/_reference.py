@@ -21,8 +21,9 @@ from typing import Dict, Iterable, List, Optional, Tuple
 
 import numpy as np
 
-from .collector import CollectStats, KernelSpec, OperandSpec
+from .collector import CollectStats
 from .heatmap import Heatmap, HeatRow, RegionHeatmap, SectorHistory
+from .spec import KernelSpec, OperandSpec
 from .tiles import TileGeometry, block_to_2d
 from .trace import (
     AccessRecord,

@@ -19,11 +19,12 @@ import numpy as np
 from . import hlo_thermo
 from .advisor import Action, advise, format_report
 from .cache import CollectionCache, spec_content_hash
-from .collector import KernelSpec, analyze, collect
+from .collector import analyze, collect
 from .heatmap import Heatmap
 from .patterns import PatternReport, detect_all, patterns_by_region
 from .render import render_ascii, render_csv, render_html, save
 from .session import Iteration, ProfileSession, SessionDiff, SessionError
+from .spec import KernelSpec
 from .trace import GridSampler, KernelWhitelist
 from .tuner import TuneAllResult, TuneResult, tune, tune_all
 

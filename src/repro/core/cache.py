@@ -64,8 +64,8 @@ from typing import Dict, Mapping, Optional, Tuple, Union
 
 import numpy as np
 
-from .collector import KernelSpec
 from .heatmap import Heatmap
+from .spec import KernelSpec
 from .trace import GridSampler
 
 #: Version of the cache key derivation AND the meta-JSON schema.  Bump

@@ -52,13 +52,14 @@ import os
 import sys
 import threading
 import time
-from typing import Callable, Dict, Iterable, List, Optional, Sequence, Tuple
+from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
 from .heatmap import Analyzer, Heatmap
 from .resilience import DEFAULT_POLICY, FaultEvent, ResiliencePolicy
-from .tiles import TileGeometry, block_to_2d
+from .spec import IndexMap, KernelSpec, OperandSpec
+from .tiles import block_to_2d
 from .trace import (
     GridSampler,
     RegionInfo,
@@ -71,8 +72,6 @@ from .trace import (
     sampled_grid_slice,
     unique_pairs,
 )
-
-IndexMap = Callable[..., Tuple[int, ...]]
 
 #: Exception types an index map / access model is *expected* to raise
 #: when it cannot evaluate a probe (non-broadcastable arithmetic, bad
@@ -100,72 +99,6 @@ class ShardError(RuntimeError):
     guard violations (stale source) keep their original types, since
     they are usage errors, not transient faults.
     """
-
-
-@dataclasses.dataclass(frozen=True)
-class OperandSpec:
-    """Describes one pallas_call operand for the Level-1 walker."""
-
-    name: str
-    shape: Tuple[int, ...]
-    dtype: np.dtype
-    block_shape: Tuple[int, ...]
-    index_map: IndexMap
-    kind: str = "load"  # 'load' | 'store' | 'accum'
-    space: str = "hbm"  # 'hbm' | 'vmem_scratch'
-    # element offset of the array's origin inside its backing buffer —
-    # models misaligned sub-array views (SpMV rowOffsets[r+1] analogue)
-    origin: Tuple[int, int] = (0, 0)
-    # True when the kernel touches this operand from ONE program only
-    # (e.g. a pl.when(last)-guarded final store of a scratch accumulator)
-    once: bool = False
-
-    @property
-    def geometry(self) -> TileGeometry:
-        return TileGeometry(
-            shape=self.shape, itemsize=np.dtype(self.dtype).itemsize, name=self.name
-        )
-
-
-@dataclasses.dataclass(frozen=True)
-class ScratchSpec:
-    """User-managed VMEM scratch (the SMEM analogue) with an access model.
-
-    ``access_model(program_id)`` returns (row_start, row_stop, col_start,
-    col_stop) slices the program touches, or None for "whole buffer".
-    """
-
-    name: str
-    shape: Tuple[int, ...]
-    dtype: np.dtype
-    access_model: Optional[Callable[..., Iterable[Tuple[int, int, int, int]]]] = None
-    kind: str = "accum"
-
-    @property
-    def geometry(self) -> TileGeometry:
-        return TileGeometry(
-            shape=self.shape, itemsize=np.dtype(self.dtype).itemsize, name=self.name
-        )
-
-
-@dataclasses.dataclass(frozen=True)
-class KernelSpec:
-    """Everything the Level-1 walker needs about one kernel launch."""
-
-    name: str
-    grid: Tuple[int, ...]
-    operands: Tuple[OperandSpec, ...]
-    scratch: Tuple[ScratchSpec, ...] = ()
-    # optional dynamic access models keyed by operand name:
-    # fn(program_id, **context_arrays) -> iterable of flat element indices
-    dynamic: Tuple[Tuple[str, Callable[..., Iterable[int]]], ...] = ()
-    # how to rebuild this spec in another process, if known.  Specs hold
-    # index-map lambdas and cannot be pickled, so a ShardedCollector
-    # worker rebuilds from this instead: either a registry ref
-    # ("gemm:v01" — also rebuilds the seeded dynamic context) or a
-    # ("module:function", args, kwargs) builder triple (see
-    # ``sourced_spec``).
-    source: Optional[object] = None
 
 
 @dataclasses.dataclass
@@ -1143,7 +1076,7 @@ class ShardedCollector:
         reach this path — the in-process re-run is the recovery, so it
         must be clean by construction.
         """
-        from ..runtime.fault import retry as _retry
+        from ..fault import retry as _retry
 
         lo, hi = bound
         k = max(1, min(int(resplit), max(hi - lo, 1)))

@@ -63,9 +63,6 @@ import numpy as np
 
 from .collector import (
     AffineModel,
-    KernelSpec,
-    OperandSpec,
-    ScratchSpec,
     _eval_index_map_batch,
     _touch_arrays_for_key,
     probe_affine_map,
@@ -78,6 +75,7 @@ from .patterns import (
     STRIDED,
     PatternReport,
 )
+from .spec import KernelSpec, OperandSpec, ScratchSpec
 from .tiles import LANES, block_to_2d
 from .trace import GridSampler, sampled_grid_array
 

@@ -1,4 +1,4 @@
-"""Model registry: CI-sized configs + the model→kernel derivation bridge.
+"""The profiler's model bridge: CI-sized models and their derived kernels.
 
 ``MODELS`` holds tiny-but-real :class:`~repro.models.model.ModelConfig`
 instances — one per family the repo ships (dense transformer, MoE,
@@ -7,29 +7,33 @@ Mamba) — each paired with the profile shapes (batch, seq) that
 profile plus a forward/backward numerical pass stay comfortably inside
 a CI worker.
 
-This module is also the *kernel bridge*: ``kernel_entry`` synthesizes a
-:class:`repro.kernels.RegistryEntry` for references of the form
-``model.<model>.<kind>`` (kind ∈ attn / mlp / moe / ssm), with the spec
-shapes derived from the model config.  ``repro.kernels.get`` delegates
-those names here, which makes every model-derived kernel a first-class
-family for ``cuthermo profile/lint/tune/check`` — including sharded
-workers, which rebuild specs from their ``model.…:variant`` source
-stamps via ``kernels.build``.
+``kernel_entry`` synthesizes a :class:`repro.kernels.RegistryEntry` for
+references of the form ``model.<model>.<kind>`` (kind ∈ attn / mlp /
+moe / ssm / unembed), with the spec shapes derived from the model
+config.  ``repro.kernels.get`` delegates those names here, which makes
+every model-derived kernel a first-class family for ``cuthermo
+profile/lint/tune/check`` — including sharded workers, which rebuild
+specs from their ``model.…:variant`` source stamps via
+``kernels.build``.
+
+This module belongs to the profiler: it imports the model and the
+kernels, and neither imports it back (``repro.kernels.get`` reaches it
+by a call-time import).
 """
 
 from __future__ import annotations
 
 import dataclasses
-from typing import Dict, Sequence, Tuple
+from typing import Dict, List, Sequence, Tuple
 
 import jax.numpy as jnp
-import numpy as np
 
-from repro.core.collector import KernelSpec
-
-from .model import ModelConfig
+from repro.core.spec import KernelSpec
+from repro.kernels import KernelVariant, RegistryEntry, flash, gemm, gmm, ssd
+from repro.models.model import ModelConfig
 
 __all__ = [
+    "KIND_RUNGS",
     "MODELS",
     "ModelEntry",
     "apply_overrides",
@@ -37,6 +41,7 @@ __all__ = [
     "kernel_entry",
     "kernel_kinds",
     "kind_spec",
+    "layer_kinds",
     "model_names",
 ]
 
@@ -181,24 +186,29 @@ _MIXER_KIND = {"attn": "attn", "mla": "attn", "mamba": "ssm"}
 _FFN_KIND = {"mlp": "mlp", "moe": "moe", "none": None}
 
 
-def kernel_kinds(cfg: ModelConfig) -> Tuple[str, ...]:
-    """Distinct kernel kinds a model's layout exercises, stable order.
+def layer_kinds(cfg: ModelConfig) -> List[Tuple[str, str]]:
+    """(layer path, kernel kind) pairs of one forward pass, in order.
 
-    Always ends with ``unembed`` — every LM closes with the logits GEMM
-    regardless of its block layout.
+    Each block contributes its mixer's kind, then its FFN's (if any);
+    the pass always ends with ``("head", "unembed")``, the logits GEMM.
     """
-    kinds: list = []
-    for block in cfg.layout():
-        for kind in (_MIXER_KIND[block.mixer], _FFN_KIND[block.ffn]):
-            if kind is not None and kind not in kinds:
-                kinds.append(kind)
-    kinds.append("unembed")
-    return tuple(kinds)
+    pairs: List[Tuple[str, str]] = []
+    for i, block in enumerate(cfg.layout()):
+        path = f"layer{i}"
+        pairs.append((path, _MIXER_KIND[block.mixer]))
+        ffn = _FFN_KIND[block.ffn]
+        if ffn is not None:
+            pairs.append((path, ffn))
+    pairs.append(("head", "unembed"))
+    return pairs
 
 
-def _moe_ids(n_tiles: int, n_experts: int) -> np.ndarray:
-    rng = np.random.default_rng(0)
-    return np.sort(rng.integers(0, n_experts, size=n_tiles)).astype(np.int64)
+def kernel_kinds(cfg: ModelConfig) -> Tuple[str, ...]:
+    """Distinct kernel kinds of :func:`layer_kinds`, first-seen order.
+
+    Always ends with ``unembed``: every LM closes with the logits GEMM.
+    """
+    return tuple(dict.fromkeys(kind for _, kind in layer_kinds(cfg)))
 
 
 def kind_spec(
@@ -211,8 +221,6 @@ def kind_spec(
     for the MLP, wider expert tiles for MoE).  The SSD scan has a single
     rung.  Raises ``ValueError`` for a kind the config doesn't use.
     """
-    from repro.kernels import flash, gemm, gmm, ssd
-
     if kind not in kernel_kinds(cfg):
         raise ValueError(
             f"model {cfg.name!r} has no {kind!r} kernels "
@@ -236,7 +244,7 @@ def kind_spec(
         m, k, n = tokens, cfg.d_model, cfg.d_ff
         bm = 32 if rung == 0 else 64
         bm = min(bm, m)
-        ids = _moe_ids(m // bm, cfg.n_experts)
+        ids = gmm.expert_ids(m // bm, cfg.n_experts)
         return gmm.gmm_spec(m, k, n, cfg.n_experts, ids, bm=bm)
     if kind == "ssm":
         d_inner = cfg.d_model * cfg.ssm_expand
@@ -263,7 +271,7 @@ _KIND_SUMMARY = {
     "unembed": "logits GEMM at (tokens, padded_vocab, d_model)",
 }
 
-_KIND_RUNGS = {
+KIND_RUNGS = {
     "attn": (("base", "dense bq=bkv tiling"),
              ("wide-kv", "wider KV blocks: fewer Q reloads")),
     "mlp": (("v01", "tile-per-program GEMM"),
@@ -282,8 +290,6 @@ def kernel_entry(ref: str):
     Raises ``KeyError`` (matching ``repro.kernels.get``'s contract) for
     malformed refs, unknown models, and kinds the model doesn't use.
     """
-    from repro import kernels as kreg
-
     parts = ref.split(".")
     if len(parts) != 3 or parts[0] != "model":
         raise KeyError(
@@ -299,7 +305,7 @@ def kernel_entry(ref: str):
             f"(layout uses: {', '.join(kernel_kinds(cfg))})"
         )
     variants = tuple(
-        kreg.KernelVariant(
+        KernelVariant(
             name=rung_name,
             build=(
                 lambda c=cfg, k=kind, b=entry.batch, s=entry.seq, r=rung:
@@ -308,9 +314,9 @@ def kernel_entry(ref: str):
             role="baseline" if rung == 0 else "optimized",
             note=note,
         )
-        for rung, (rung_name, note) in enumerate(_KIND_RUNGS[kind])
+        for rung, (rung_name, note) in enumerate(KIND_RUNGS[kind])
     )
-    return kreg.RegistryEntry(
+    return RegistryEntry(
         name=ref,
         summary=f"{model_name}: {_KIND_SUMMARY[kind]}",
         variants=variants,

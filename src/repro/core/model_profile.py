@@ -5,14 +5,11 @@ profiles a *model* — every Pallas-modeled kernel its forward (and,
 optionally, backward) pass invokes — into ONE session iteration whose
 manifest carries per-layer attribution (artifact v5):
 
-1. **Kernel-call interception.**  ``intercept()`` monkeypatches the
-   ``kernels/`` spec-builder entry points (``flash.flash_spec``,
-   ``gemm.gemm_v01_spec``, ...) so every spec built while a
-   ``layer_scope`` is active is recorded as a :class:`KernelCall` with
-   the layer path that built it.  ``discover()`` walks the model's
-   ``layout()`` under the shim — layer by layer, block kind by block
-   kind — so the specs that get profiled are, verifiably, the ones the
-   derivation actually constructed, each attributed to its layer.
+1. **Discovery.**  ``discover()`` walks the model's ``layout()`` layer
+   by layer, block kind by block kind
+   (:func:`repro.core.model_kernels.layer_kinds`), and builds each
+   kernel's spec with :func:`repro.core.model_kernels.kind_spec` at the
+   model's shapes, attributed to its layer.
 2. **HLO-level sweep.**  The model forward (``value_and_grad`` of the
    loss when ``backward=True``) is jitted and compiled; the optimized
    HLO text runs through :mod:`repro.core.hlo_thermo` (collective /
@@ -27,7 +24,7 @@ manifest carries per-layer attribution (artifact v5):
 
 Discovered kernels are stamped with ``model.<model>.<kind>`` family
 refs (``repro.kernels.get`` delegates those to
-``repro.models.registry.kernel_entry``), which makes them first-class
+``repro.core.model_kernels.kernel_entry``), which makes them first-class
 tunable families: ``cuthermo tune model.transformer-tiny.mlp`` walks
 the derived ladder, ``cuthermo lint``/``check`` accept the refs, and
 sharded workers rebuild the specs from the stamps.
@@ -42,17 +39,23 @@ memory traffic.
 
 from __future__ import annotations
 
-import contextlib
-import contextvars
 import dataclasses
 import json
 import os
 from pathlib import Path
-from typing import Dict, List, Mapping, Optional, Sequence, Tuple, Union
+from typing import Dict, List, Optional, Sequence, Union
 
-import numpy as np
+import jax
+import jax.numpy as jnp
 
-from repro.core.collector import KernelSpec
+from repro.core import hlo_cost, hlo_thermo
+from repro.core.model_kernels import (
+    KIND_RUNGS,
+    apply_overrides,
+    get_model,
+    kind_spec,
+    layer_kinds,
+)
 from repro.core.session import (
     Iteration,
     ProfileSession,
@@ -60,19 +63,18 @@ from repro.core.session import (
     load_iteration,
     profile_kernel,
 )
+from repro.core.spec import KernelSpec
 from repro.core.trace import GridSampler
-from repro.runtime.fault import Preempted
+from repro.fault import Preempted
+from repro.models import build_model
 
 __all__ = [
     "DiscoveredKernel",
-    "KernelCall",
     "MODEL_JOURNAL",
     "bwd_spec",
     "discover",
     "hlo_sweep",
-    "intercept",
     "iteration_transactions",
-    "layer_scope",
     "layers_table",
     "profile_model",
 ]
@@ -80,90 +82,6 @@ __all__ = [
 #: Name of the resumable-run journal ``profile_model`` keeps at the
 #: session root while a whole-model profile is in flight.
 MODEL_JOURNAL = "model.journal.json"
-
-
-# ---------------------------------------------------------------------------
-# the interception shim
-# ---------------------------------------------------------------------------
-
-#: Layer path active for spec builds on this thread ("" = no scope:
-#: builder calls are NOT recorded — registry/tuner builds stay silent).
-_LAYER: contextvars.ContextVar[str] = contextvars.ContextVar(
-    "cuthermo_layer", default=""
-)
-
-
-@dataclasses.dataclass(frozen=True)
-class KernelCall:
-    """One intercepted spec-builder call, attributed to a layer."""
-
-    layer: str  # layer path active at build time ("layer0", "head", ...)
-    entry: str  # "module:function" of the kernels/ entry point
-    spec: KernelSpec
-
-
-@contextlib.contextmanager
-def layer_scope(path: str):
-    """Attribute spec builds inside this block to layer ``path``."""
-    token = _LAYER.set(path)
-    try:
-        yield
-    finally:
-        _LAYER.reset(token)
-
-
-def _entry_points() -> Tuple[Tuple[object, str], ...]:
-    """The kernels/ spec builders the model derivation goes through."""
-    from repro.kernels import flash, gemm, gmm, ssd
-
-    return (
-        (flash, "flash_spec"),
-        (gemm, "gemm_v01_spec"),
-        (gemm, "gemm_v02_spec"),
-        (gmm, "gmm_spec"),
-        (ssd, "ssd_chunk_spec"),
-    )
-
-
-@contextlib.contextmanager
-def intercept():
-    """Record every layer-scoped kernels/ spec build into the yielded list.
-
-    Monkeypatches the spec-builder entry points for the duration of the
-    block (always restored); a build with no active :func:`layer_scope`
-    passes through unrecorded, so unrelated registry traffic inside the
-    block stays invisible.
-    """
-    calls: List[KernelCall] = []
-    patched: List[Tuple[object, str, object]] = []
-
-    def _wrap(module, fn_name, fn):
-        def shim(*args, **kwargs):
-            spec = fn(*args, **kwargs)
-            layer = _LAYER.get()
-            if layer:
-                calls.append(
-                    KernelCall(
-                        layer=layer,
-                        entry=f"{module.__name__}:{fn_name}",
-                        spec=spec,
-                    )
-                )
-            return spec
-
-        shim.__name__ = fn_name
-        shim.__wrapped__ = fn
-        return shim
-
-    try:
-        for module, fn_name in _entry_points():
-            fn = getattr(module, fn_name)
-            patched.append((module, fn_name, fn))
-            setattr(module, fn_name, _wrap(module, fn_name, fn))
-        yield calls
-    finally:
-        for module, fn_name, fn in patched:
-            setattr(module, fn_name, fn)
 
 
 # ---------------------------------------------------------------------------
@@ -180,7 +98,6 @@ class DiscoveredKernel:
     kind: str  # 'attn' | 'mlp' | 'moe' | 'ssm' | 'unembed'
     family: str  # tunable family ref: "model.<model>.<kind>"
     spec: KernelSpec  # source-stamped (shard workers rebuild from it)
-    entry: str  # intercepted kernels/ entry point ("module:function")
     backward: bool = False
 
 
@@ -193,8 +110,6 @@ def bwd_spec(cfg, kind: str, batch: int, seq: int, rung: int = 0) -> KernelSpec:
     ``ShardedCollector`` worker can rebuild the spec from its
     ``("repro.core.model_profile:bwd_spec", ...)`` source triple.
     """
-    from repro.models.registry import kind_spec
-
     fwd = kind_spec(cfg, kind, batch, seq, rung=rung)
     flipped = {"load": "store", "store": "load"}
     operands = tuple(
@@ -204,21 +119,6 @@ def bwd_spec(cfg, kind: str, batch: int, seq: int, rung: int = 0) -> KernelSpec:
     return dataclasses.replace(
         fwd, name=f"{fwd.name}_bwd", operands=operands
     )
-
-
-def _layer_kinds(cfg) -> List[Tuple[str, str]]:
-    """(layer path, kernel kind) pairs of one forward pass, in order."""
-    from repro.models.registry import _FFN_KIND, _MIXER_KIND
-
-    pairs: List[Tuple[str, str]] = []
-    for i, block in enumerate(cfg.layout()):
-        path = f"layer{i}"
-        pairs.append((path, _MIXER_KIND[block.mixer]))
-        ffn = _FFN_KIND[block.ffn]
-        if ffn is not None:
-            pairs.append((path, ffn))
-    pairs.append(("head", "unembed"))
-    return pairs
 
 
 def discover(
@@ -232,45 +132,33 @@ def discover(
 ) -> List[DiscoveredKernel]:
     """Walk one model pass and return its kernels with layer attribution.
 
-    Runs the per-layer derivation under :func:`intercept`, so every
-    returned spec is one the shim actually observed being built inside
-    its layer's scope.  ``backward=True`` appends a ``.bwd``
-    (kind-swapped) kernel per forward kernel.  Specs are source-stamped
-    for shard rebuild: with the registry's ``model.…:<rung>`` string
-    ref when the config and shapes are the registry defaults
-    (``default_shapes``), otherwise with a picklable builder triple.
+    One kernel per (layer, kind) of :func:`layer_kinds`, its spec built
+    by :func:`kind_spec` at the model's shapes.  ``backward=True``
+    appends a ``.bwd`` (kind-swapped) kernel per forward kernel.  Specs
+    are source-stamped for shard rebuild: with the registry's
+    ``model.…:<rung>`` string ref when the config and shapes are the
+    registry defaults (``default_shapes``), otherwise with a picklable
+    builder triple.
     """
-    from repro.models.registry import _KIND_RUNGS, kind_spec
-
-    pairs = _layer_kinds(cfg)
-    with intercept() as calls:
-        for path, kind in pairs:
-            with layer_scope(path):
-                kind_spec(cfg, kind, batch, seq)
-    if len(calls) != len(pairs):  # the shim is the source of truth
-        raise RuntimeError(
-            f"kernel interception out of sync: walked {len(pairs)} "
-            f"layer kinds but recorded {len(calls)} builder calls"
-        )
     discovered: List[DiscoveredKernel] = []
-    for (path, kind), call in zip(pairs, calls):
-        rung_name = _KIND_RUNGS[kind][0][0]
+    for path, kind in layer_kinds(cfg):
+        rung_name = KIND_RUNGS[kind][0][0]
         if default_shapes:
             source: object = f"model.{model_name}.{kind}:{rung_name}"
         else:
             source = (
-                "repro.models.registry:kind_spec",
+                "repro.core.model_kernels:kind_spec",
                 (cfg, kind, batch, seq),
                 {"rung": 0},
             )
+        spec = kind_spec(cfg, kind, batch, seq)
         discovered.append(
             DiscoveredKernel(
                 name=f"{path}.{kind}",
                 layer=path,
                 kind=kind,
                 family=f"model.{model_name}.{kind}",
-                spec=dataclasses.replace(call.spec, source=source),
-                entry=call.entry,
+                spec=dataclasses.replace(spec, source=source),
             )
         )
     if backward:
@@ -308,12 +196,6 @@ def hlo_sweep(cfg, batch: int, seq: int, backward: bool = False) -> Dict:
     :func:`repro.core.hlo_cost.analyze`.  Returns the JSON-ready
     ``layers.hlo`` manifest block.
     """
-    import jax
-    import jax.numpy as jnp
-
-    from repro.core import hlo_cost, hlo_thermo
-    from repro.models import build_model
-
     model = build_model(cfg)
     params = model.abstract_params()
     toks = jax.ShapeDtypeStruct((batch, seq), jnp.int32)
@@ -462,10 +344,10 @@ def profile_model(
 
     The run is preemption-safe: a journal (:data:`MODEL_JOURNAL`) lives
     at the session root while the profile is in flight, and when
-    ``preemption`` (e.g. a :class:`repro.runtime.fault.PreemptionHandler`)
+    ``preemption`` (e.g. a :class:`repro.fault.PreemptionHandler`)
     reports ``requested`` between kernels, the kernels profiled so far
     are flushed as an emergency *partial* iteration, the journal records
-    it, and :class:`~repro.runtime.fault.Preempted` is raised.
+    it, and :class:`~repro.fault.Preempted` is raised.
     ``resume=True`` picks such a run back up: the journal is validated
     against the requested arguments, the partial iteration's kernels are
     reused verbatim, and only the remainder is profiled — the final
@@ -477,8 +359,6 @@ def profile_model(
     malformed ``--config`` override or an invalid resume (the CLI maps
     both to exit 2).
     """
-    from repro.models.registry import apply_overrides, get_model
-
     entry = get_model(name)
     cfg = apply_overrides(entry.config, overrides)
     batch, seq = entry.batch, entry.seq

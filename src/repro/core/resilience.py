@@ -22,7 +22,7 @@ the two pieces every layer shares:
 
 The injection side (deterministically *causing* these faults) lives in
 :mod:`repro.core.faultinject`; the generic retry/preemption primitives
-in :mod:`repro.runtime.fault`.  See ``docs/robustness.md`` for the full
+in :mod:`repro.fault`.  See ``docs/robustness.md`` for the full
 fault model.
 """
 

@@ -51,12 +51,13 @@ import numpy as np
 
 from .advisor import Action, advise
 from .cache import CacheKeyError, CollectionCache, spec_content_hash
-from .collector import KernelSpec, ShardedCollector, analyze
+from .collector import ShardedCollector, analyze
 from .diff import HeatmapDiff, diff as diff_heatmaps
 from .heatmap import Heatmap, RegionHeatmap
 from .patterns import PatternReport, detect_all
 from .render import dedupe_stem, slugify
 from .resilience import FaultEvent
+from .spec import KernelSpec
 from .tiles import TileGeometry
 from .trace import GridSampler, RegionInfo, ShardInfo
 

@@ -11,7 +11,7 @@ advice (:mod:`repro.core.advisor` Actions) and the bookkeeping
    is expanded into concrete :class:`Candidate` variants — the kernel
    registry's hand-written ladder steps (``gemm:v01``, ``spmv:zigzag``,
    ...) plus *generated* parametric candidates synthesized by structural
-   surgery on the baseline :class:`~.collector.KernelSpec` (re-tile the
+   surgery on the baseline :class:`~.spec.KernelSpec` (re-tile the
    block/grid, pin a hot operand, align a misaligned view, transpose a
    strided layout, drop an abused scratch buffer).
 2. **Re-profile.**  Candidates are profiled through the same
@@ -40,10 +40,10 @@ from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
-from ..runtime.fault import Preempted
+from ..fault import Preempted
 from .advisor import Action
 from .cache import CollectionCache
-from .collector import KernelSpec, OperandSpec, ShardedCollector
+from .collector import ShardedCollector
 from .diff import HeatmapDiff, diff as diff_heatmaps
 from .heatmap import Heatmap
 from .lint import static_transactions
@@ -54,6 +54,7 @@ from .session import (
     _effective_region_map,
     profile_kernel,
 )
+from .spec import KernelSpec, OperandSpec
 from .trace import GridSampler
 
 #: Default number of candidate re-profiles one ``tune`` call may spend.
@@ -1262,9 +1263,9 @@ def tune_all(
     ``candidate-failure`` fault on its family's loop and skipped — one
     broken candidate never aborts the whole schedule.  ``preemption``
     (any object with a boolean ``requested`` attribute, e.g. a
-    :class:`repro.runtime.fault.PreemptionHandler`) is checked at every
+    :class:`repro.fault.PreemptionHandler`) is checked at every
     round boundary: when set, the scheduler raises
-    :class:`~repro.runtime.fault.Preempted` *between* rounds, after the
+    :class:`~repro.fault.Preempted` *between* rounds, after the
     in-flight round's iterations have durably committed — the session
     is left resumable (``cuthermo tune --all --resume`` replays the
     journaled run deterministically; completed profiles come back

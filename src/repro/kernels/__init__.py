@@ -28,7 +28,7 @@ from typing import Callable, Dict, Optional, Tuple
 
 import numpy as np
 
-from repro.core.collector import KernelSpec
+from repro.core.spec import KernelSpec
 from repro.core.trace import GridSampler
 
 from . import (
@@ -115,11 +115,6 @@ def _spmv_context() -> Dict[str, np.ndarray]:
 def _hist_context() -> Dict[str, np.ndarray]:
     rng = np.random.default_rng(0)
     return {"cells": rng.integers(0, 2048, size=65536).astype(np.int64)}
-
-
-def _gmm_ids() -> np.ndarray:
-    rng = np.random.default_rng(0)
-    return np.sort(rng.integers(0, 8, size=8)).astype(np.int64)
 
 
 REGISTRY: Dict[str, RegistryEntry] = {
@@ -268,7 +263,9 @@ REGISTRY: Dict[str, RegistryEntry] = {
             variants=(
                 KernelVariant(
                     "default",
-                    lambda: gmm.gmm_spec(1024, 512, 512, 8, _gmm_ids()),
+                    lambda: gmm.gmm_spec(
+                        1024, 512, 512, 8, gmm.expert_ids(8, 8)
+                    ),
                 ),
             ),
             sampler=_full,
@@ -366,7 +363,7 @@ def get(name: str) -> RegistryEntry:
     """Look up a registry entry; raises KeyError with the known names.
 
     Families named ``model.<model>.<kind>`` are *model-derived*: they
-    are synthesized on demand by ``repro.models.registry.kernel_entry``
+    are synthesized on demand by ``repro.core.model_kernels.kernel_entry``
     from a model's layer layout, so everything that consumes a registry
     entry — ``cuthermo profile/lint/tune/check`` and ``ShardedCollector``
     workers rebuilding specs from source stamps — works on them without
@@ -374,9 +371,11 @@ def get(name: str) -> RegistryEntry:
     default scope) ever listing them.
     """
     if name.startswith("model."):
-        from repro.models import registry as model_registry
+        # the one reference from the kernels up to the profiler's model
+        # bridge, made at call time so no kernel import loads the model
+        from repro.core.model_kernels import kernel_entry
 
-        return model_registry.kernel_entry(name)
+        return kernel_entry(name)
     try:
         return REGISTRY[name]
     except KeyError:

@@ -16,10 +16,10 @@ from typing import Callable, Dict, Tuple
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.collector import OperandSpec
+from repro.core.spec import OperandSpec
 
 from . import (
-    _gmm_ids, build, flash, gemm, gmm, gramschm, histogram, paged_attn,
+    build, flash, gemm, gmm, gramschm, histogram, paged_attn,
     ragged_flash, ref, spmv, ssd, ttm,
 )
 
@@ -102,7 +102,8 @@ def _gramschm_inputs(rng):
 
 def _gmm_inputs(rng):
     ops = _operands("gmm:default")
-    return _normal(rng, ops["X"]), _normal(rng, ops["W"]), _gmm_ids().astype(np.int32)
+    ids = gmm.expert_ids(8, 8).astype(np.int32)
+    return _normal(rng, ops["X"]), _normal(rng, ops["W"]), ids
 
 
 def _ragged_inputs(rng):

@@ -29,7 +29,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 
-from repro.core.collector import KernelSpec, OperandSpec, ScratchSpec
+from repro.core.spec import KernelSpec, OperandSpec, ScratchSpec
 from repro.core.tiles import tile_rows
 from repro.kernels.mxu import dot_precision
 

@@ -22,7 +22,7 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.core.collector import KernelSpec, OperandSpec
+from repro.core.spec import KernelSpec, OperandSpec
 from repro.kernels.mxu import dot_precision
 
 
@@ -91,6 +91,12 @@ def gmm_ref(x: jax.Array, w: jax.Array, tile_expert_ids: jax.Array, bm: int = 12
     xt = x.reshape(n_tiles, bm, k)
     wt = w[tile_expert_ids]  # (n_tiles, K, N)
     return jnp.einsum("tbk,tkn->tbn", xt, wt).reshape(m, n).astype(x.dtype)
+
+
+def expert_ids(n_tiles: int, n_experts: int) -> np.ndarray:
+    """Seeded sorted expert id per row tile: the profiler's uniform routing."""
+    rng = np.random.default_rng(0)
+    return np.sort(rng.integers(0, n_experts, size=n_tiles)).astype(np.int64)
 
 
 def gmm_spec(

@@ -25,7 +25,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 
-from repro.core.collector import KernelSpec, OperandSpec
+from repro.core.spec import KernelSpec, OperandSpec
 from repro.core.tiles import tile_rows
 from repro.kernels.mxu import dot_precision
 

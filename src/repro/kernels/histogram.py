@@ -33,7 +33,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 
-from repro.core.collector import KernelSpec, OperandSpec
+from repro.core.spec import KernelSpec, OperandSpec, ScratchSpec
 from repro.core.tiles import tile_rows
 
 
@@ -186,8 +186,6 @@ def hist_opt_spec(n: int, n_bins: int, block: int = 1024) -> KernelSpec:
 
 
 def hist_opt2_spec(n: int, n_bins: int, block: int = 1024) -> KernelSpec:
-    from repro.core.collector import ScratchSpec
-
     n_blocks = n // block
     return KernelSpec(
         name="find_cell_counts_opt2",

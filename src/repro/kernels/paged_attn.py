@@ -27,7 +27,7 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.core.collector import KernelSpec, OperandSpec, ScratchSpec
+from repro.core.spec import KernelSpec, OperandSpec, ScratchSpec
 from repro.kernels.mxu import dot_precision
 
 NEG_INF = -1e30

@@ -30,7 +30,7 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.core.collector import KernelSpec, OperandSpec, ScratchSpec
+from repro.core.spec import KernelSpec, OperandSpec, ScratchSpec
 
 
 def _ttm_scratch_kernel(vals_ref, urows_ref, y_ref, y_shr):

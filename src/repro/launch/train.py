@@ -28,15 +28,13 @@ from repro.data import DataConfig, SyntheticSource, TokenPipeline
 from repro.models import build_model
 from repro.optim import adamw, cosine_warmup
 from repro.parallel.sharding import fixup_specs, make_rules, specs_from_logical
-from repro.runtime import (
-    PreemptionHandler,
-    StragglerMonitor,
+from repro.fault import PreemptionHandler, StragglerMonitor
+from repro.runtime.train_loop import (
     TrainConfig,
     build_train_step,
     init_state,
     run,
 )
-from repro.runtime.train_loop import TrainState
 
 
 def make_mesh_from_devices():

@@ -9,7 +9,6 @@ from . import (
     model,
     moe,
     params,
-    registry,
     transformer,
 )
 from .model import LM, ModelConfig, build_model
@@ -26,6 +25,5 @@ __all__ = [
     "model",
     "moe",
     "params",
-    "registry",
     "transformer",
 ]

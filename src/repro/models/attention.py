@@ -4,7 +4,7 @@ One execution path, ``flash_xla``: an online-softmax scan over KV chunks
 with a custom VJP.  Same memory behaviour class as flash attention (O(S)
 live activations instead of O(S^2)), pure XLA, on every backend.  The
 Pallas flash kernel in ``repro.kernels.flash`` is not called from here;
-the profiler models it for each attention layer (``repro.models.registry``).
+the profiler models it for each attention layer (``repro.core.model_kernels``).
 
 KV caches are plain dicts of arrays + a scalar length; decode updates are
 ``dynamic_update_slice`` so a serve step compiles to a fixed shape.

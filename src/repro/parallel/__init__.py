@@ -1,15 +1,17 @@
-"""repro.parallel — mesh rules, sharding, compression, pipeline."""
+"""repro.parallel — mesh rules, sharding, compression, pipeline.
 
-from . import compression, pipeline, sharding
+Only ``sharding`` is loaded with the package; ``compression`` and
+``pipeline`` belong to training and are imported by name.
+"""
+
+from . import sharding
 from .sharding import Rules, cache_specs, constrain, make_rules, shardings_from_logical, specs_from_logical
 
 __all__ = [
     "Rules",
     "cache_specs",
-    "compression",
     "constrain",
     "make_rules",
-    "pipeline",
     "sharding",
     "shardings_from_logical",
     "specs_from_logical",

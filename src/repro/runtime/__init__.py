@@ -1,25 +1,11 @@
-"""repro.runtime — training loop, serving loop, fault tolerance."""
+"""repro.runtime — the serving loop and its host spans.
 
-from . import fault, serve, spans, train_loop
-from .fault import Preempted, PreemptionHandler, StragglerMonitor, retry
+Training code imports ``repro.runtime.train_loop`` by name, and the
+fault primitives live in ``repro.fault``, so importing ``Server`` loads
+neither.
+"""
+
+from . import serve, spans
 from .serve import Request, ServeConfig, Server
-from .train_loop import TrainConfig, TrainState, build_train_step, init_state, run
 
-__all__ = [
-    "Preempted",
-    "PreemptionHandler",
-    "Request",
-    "ServeConfig",
-    "Server",
-    "StragglerMonitor",
-    "TrainConfig",
-    "TrainState",
-    "build_train_step",
-    "fault",
-    "init_state",
-    "retry",
-    "run",
-    "serve",
-    "spans",
-    "train_loop",
-]
+__all__ = ["Request", "ServeConfig", "Server", "serve", "spans"]

@@ -13,7 +13,7 @@ labels) -> (state, metrics)`` with:
   * donated state (in-place buffers on TPU).
 
 Hooks (thermo profiling, straggler monitor, checkpointing) observe each
-step from the host side — see ``repro.runtime.fault`` and the examples.
+step from the host side — see ``repro.fault`` and the examples.
 """
 
 from __future__ import annotations

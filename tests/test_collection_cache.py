@@ -16,8 +16,8 @@ from repro.core.cache import (
     callable_fingerprint,
     spec_content_hash,
 )
-from repro.core.collector import KernelSpec, OperandSpec
 from repro.core.session import heatmaps_equal, profile_kernel
+from repro.core.spec import KernelSpec, OperandSpec
 from repro.core.trace import GridSampler
 
 

@@ -2,8 +2,8 @@
 
 import numpy as np
 
-from repro.core import analyze, collect
-from repro.core.collector import KernelSpec, OperandSpec, ScratchSpec, drain_dynamic
+from repro.core.collector import analyze, collect, drain_dynamic
+from repro.core.spec import KernelSpec, OperandSpec, ScratchSpec
 from repro.core.trace import GridSampler, KernelWhitelist, sampled_grid
 
 

@@ -2,7 +2,7 @@
 
 import numpy as np
 
-from repro.core import analyze
+from repro.core.collector import analyze
 from repro.core.diff import diff
 from repro.core.trace import GridSampler
 from repro.kernels.gemm import gemm_v00_spec, gemm_v01_spec

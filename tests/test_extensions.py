@@ -119,7 +119,7 @@ print(json.dumps({"diff": float(jnp.abs(y - y_ref).max())}))
 
 
 def _toy_heatmap():
-    from repro.core import analyze
+    from repro.core.collector import analyze
     from repro.core.trace import GridSampler
     from repro.kernels.gemm import gemm_v00_spec
 

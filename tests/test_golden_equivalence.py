@@ -8,21 +8,15 @@ derived transaction model (``sector_transactions``, ``waste_ratio``).
 import numpy as np
 import pytest
 
-from repro.core import analyze
 from repro.core._reference import (
     ReferenceAnalyzer,
     analyze_reference,
     collect_reference,
     drain_dynamic_reference,
 )
-from repro.core.collector import (
-    KernelSpec,
-    OperandSpec,
-    ScratchSpec,
-    collect,
-    drain_dynamic,
-)
+from repro.core.collector import analyze, collect, drain_dynamic
 from repro.core.heatmap import Analyzer
+from repro.core.spec import OperandSpec
 from repro.core.trace import GridSampler
 
 

@@ -17,12 +17,7 @@ from repro import kernels as kreg
 from repro.cli import main as cli_main
 from repro.core.advisor import advise_static
 from repro.core.check import CheckError, check_static
-from repro.core.collector import (
-    KernelSpec,
-    OperandSpec,
-    analyze,
-    probe_affine_map,
-)
+from repro.core.collector import analyze, probe_affine_map
 from repro.core.lint import (
     COVERAGE_GAP,
     DEAD_OPERAND,
@@ -43,6 +38,7 @@ from repro.core.patterns import (
     detect_all,
 )
 from repro.core.session import ProfileSession
+from repro.core.spec import KernelSpec, OperandSpec
 from repro.core.trace import GridSampler
 from repro.core.tuner import trajectories_from_session, tune
 

@@ -1,8 +1,7 @@
-"""Whole-model profiling: interception, discovery, per-layer rollup.
+"""Whole-model profiling: discovery, per-layer rollup.
 
 Covers the ``repro.core.model_profile`` walker (the ``cuthermo model``
-engine): the kernel-call interception shim, per-layer discovery with
-source-stamped specs, the backward kind-swap model, the v5 layer-table
+engine): per-layer discovery with source-stamped specs, the backward kind-swap model, the v5 layer-table
 partition invariant (property-tested: for ANY partition of the profiled
 kernels into layers, per-layer totals sum to the iteration total — and
 ``_validate_layers`` rejects everything that is not a partition), the
@@ -17,6 +16,7 @@ import pytest
 
 from repro.core import model_profile as mp
 from repro.core.heatmap import Heatmap, RegionHeatmap
+from repro.core.model_kernels import MODELS, get_model, kernel_kinds, kind_spec
 from repro.core.session import (
     SessionError,
     _validate_layers,
@@ -25,59 +25,6 @@ from repro.core.session import (
 )
 from repro.core.tiles import TileGeometry
 from repro.core.trace import RegionInfo
-from repro.models.registry import MODELS, get_model, kind_spec
-
-
-# ---------------------------------------------------------------------------
-# interception shim
-# ---------------------------------------------------------------------------
-
-
-def test_intercept_records_only_scoped_builds():
-    from repro.kernels import gemm
-
-    original = gemm.gemm_v01_spec
-    with mp.intercept() as calls:
-        gemm.gemm_v01_spec(16, 16, 16, bm=8)  # no scope: invisible
-        assert calls == []
-        with mp.layer_scope("layer0"):
-            spec = gemm.gemm_v01_spec(16, 16, 16, bm=8)
-        gemm.gemm_v01_spec(16, 16, 16, bm=8)  # scope closed again
-    assert len(calls) == 1
-    (call,) = calls
-    assert call.layer == "layer0"
-    assert call.entry == "repro.kernels.gemm:gemm_v01_spec"
-    assert call.spec == spec
-    # the monkeypatch is fully restored
-    assert gemm.gemm_v01_spec is original
-
-
-def test_intercept_restores_on_error():
-    from repro.kernels import flash, gemm, gmm, ssd
-
-    before = {
-        (m.__name__, f): getattr(m, f)
-        for m, f in ((flash, "flash_spec"), (gemm, "gemm_v01_spec"),
-                     (gemm, "gemm_v02_spec"), (gmm, "gmm_spec"),
-                     (ssd, "ssd_chunk_spec"))
-    }
-    with pytest.raises(RuntimeError):
-        with mp.intercept():
-            raise RuntimeError("boom")
-    for (mod_name, fn_name), fn in before.items():
-        mod = __import__(mod_name, fromlist=[fn_name])
-        assert getattr(mod, fn_name) is fn, (mod_name, fn_name)
-
-
-def test_nested_layer_scopes_attribute_innermost():
-    from repro.kernels import gemm
-
-    with mp.intercept() as calls:
-        with mp.layer_scope("outer"):
-            with mp.layer_scope("inner"):
-                gemm.gemm_v01_spec(16, 16, 16, bm=8)
-            gemm.gemm_v01_spec(16, 16, 16, bm=8)
-    assert [c.layer for c in calls] == ["inner", "outer"]
 
 
 # ---------------------------------------------------------------------------
@@ -118,7 +65,7 @@ def test_discover_with_non_default_shapes_uses_builder_triples():
     )
     for d in found:
         fn_ref, args, kwargs = d.spec.source
-        assert fn_ref == "repro.models.registry:kind_spec"
+        assert fn_ref == "repro.core.model_kernels:kind_spec"
         assert args == (cfg, d.kind, entry.batch, entry.seq)
         assert kwargs == {"rung": 0}
 
@@ -341,8 +288,6 @@ def test_every_model_kind_has_a_ladder_improvement_or_single_rung():
     from repro.core.lint import lint_ref
 
     for model_name in MODELS:
-        from repro.models.registry import kernel_kinds
-
         for kind in kernel_kinds(MODELS[model_name].config):
             entry = kreg.get(f"model.{model_name}.{kind}")
             costs = []

@@ -12,9 +12,9 @@ import pytest
 
 from repro.configs import ARCH_IDS, SUBQUADRATIC, get_config
 from repro.models import build_model
-from repro.models.registry import MODELS, get_model, model_names
+from repro.core.model_kernels import MODELS, get_model, model_names
 from repro.optim import adamw, constant
-from repro.runtime import TrainConfig, build_train_step, init_state
+from repro.runtime.train_loop import TrainConfig, build_train_step, init_state
 
 
 @pytest.mark.parametrize("arch_id", ARCH_IDS + ["granite-4.0-h-small"])

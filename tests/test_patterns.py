@@ -3,7 +3,6 @@
 import numpy as np
 import pytest
 
-from repro.core import detect_all
 from repro.core.heatmap import Analyzer
 from repro.core.patterns import (
     FALSE_SHARING,
@@ -12,6 +11,7 @@ from repro.core.patterns import (
     MISALIGNMENT,
     SCRATCH_ABUSE,
     STRIDED,
+    detect_all,
 )
 from repro.core.tiles import TileGeometry
 from repro.core.trace import AccessRecord, RegionInfo, TraceBuffer
@@ -104,7 +104,7 @@ def test_coalesced_clean():
 
 
 def test_advisor_ranks_by_saving():
-    from repro.core import advise
+    from repro.core.advisor import advise
 
     recs = [(p, [(t, p) for t in range(8)]) for p in range(8)]
     hm = _heatmap(recs)

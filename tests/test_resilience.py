@@ -24,7 +24,7 @@ from repro.core.resilience import (
 )
 from repro.core.session import heatmaps_equal
 from repro.core.trace import GridSampler
-from repro.runtime.fault import Preempted
+from repro.fault import Preempted
 
 
 # -- primitives --------------------------------------------------------------

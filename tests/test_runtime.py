@@ -15,20 +15,10 @@ from repro.data import DataConfig, SyntheticSource, TokenPipeline
 from repro.models import ModelConfig, build_model
 from repro.optim import adamw, constant, cosine_warmup
 from repro.parallel.compression import CompressionConfig, compress, decompress, init_error_buffer
-from repro.runtime import (
-    Preempted,
-    PreemptionHandler,
-    Request,
-    ServeConfig,
-    Server,
-    StragglerMonitor,
-    TrainConfig,
-    build_train_step,
-    init_state,
-    retry,
-    run,
-)
+from repro.fault import Preempted, PreemptionHandler, StragglerMonitor, retry
+from repro.runtime import Request, ServeConfig, Server
 from repro.runtime import serve as serve_mod
+from repro.runtime.train_loop import TrainConfig, build_train_step, init_state, run
 
 
 def _tiny():

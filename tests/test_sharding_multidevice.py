@@ -81,7 +81,7 @@ def test_sharded_train_step_matches_single_device():
     res = _run_sub("""
     from repro.models import ModelConfig, build_model
     from repro.optim import adamw, constant
-    from repro.runtime import TrainConfig, build_train_step, init_state
+    from repro.runtime.train_loop import TrainConfig, build_train_step, init_state
     from repro.parallel.sharding import make_rules, specs_from_logical, fixup_specs
 
     cfg = ModelConfig(name="t", family="dense", n_layers=2, d_model=64,

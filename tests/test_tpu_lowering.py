@@ -92,7 +92,7 @@ def test_cost_parser_reads_tpu_compiled_model(one_chip):
 
     from repro.core import hlo_cost
     from repro.models import build_model
-    from repro.models.registry import MODELS
+    from repro.core.model_kernels import MODELS
 
     entry = MODELS["transformer-tiny"]
     model = build_model(entry.config)

@@ -6,9 +6,10 @@ import numpy as np
 import pytest
 
 from repro.core.advisor import Action
-from repro.core.collector import KernelSpec, OperandSpec, analyze
+from repro.core.collector import analyze
 from repro.core.patterns import MISALIGNMENT, detect_all
 from repro.core.session import ARTIFACT_VERSION, ProfileSession
+from repro.core.spec import KernelSpec, OperandSpec
 from repro.core.trace import GridSampler
 from repro.core.tuner import (
     VMEM_PIN_BUDGET_BYTES,

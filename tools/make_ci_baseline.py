@@ -32,7 +32,7 @@ BASELINE_REFS = {
     "gemm": "gemm:v01",
     "gramschm": "gramschm:opt",
     # a whole-model-derived family: the transformer-tiny FFN GEMM on its
-    # blocked rung, synthesized by repro.models.registry.kernel_entry.
+    # blocked rung, synthesized by repro.core.model_kernels.kernel_entry.
     # Stored under the full family name — that is the name `cuthermo
     # profile --kernel model.transformer-tiny.mlp:v02` aligns on.
     "model.transformer-tiny.mlp": "model.transformer-tiny.mlp:v02",
