@@ -8,6 +8,8 @@ the profiler models it for each attention layer (``repro.core.model_kernels``).
 
 KV caches are plain dicts of arrays + a scalar length; decode updates are
 ``dynamic_update_slice`` so a serve step compiles to a fixed shape.
+A one-token query on a cache takes ``decode_attention``: each KV head's
+cache is read once, as stored, for its whole query group.
 
 MLA (DeepSeek-V2/V3 multi-head latent attention) caches the 512-d latent
 + 64-d rope key only; decode uses the *absorbed* formulation (q projected
@@ -244,7 +246,7 @@ def attention_ref(
     window: Optional[int] = None,
     scale: Optional[float] = None,  # score scale; None: 1/sqrt(D)
 ) -> jax.Array:
-    """Naive O(S^2) oracle (tests + tiny decode)."""
+    """Naive O(S^2) oracle for the tests."""
     b, sq, h, d = q.shape
     kvh = k.shape[2]
     g = h // kvh
@@ -266,6 +268,39 @@ def attention_ref(
     out = jnp.einsum("bhqk,bkhd->bqhd", p.astype(v.dtype), v,
                      preferred_element_type=jnp.float32)
     return out.astype(q.dtype)
+
+
+def decode_attention(
+    q: jax.Array,  # (B, 1, H, D)
+    k: jax.Array,  # (B, Skv, KV, D)
+    v: jax.Array,  # (B, Skv, KV, D)
+    q_positions: jax.Array,  # (B, 1) int32
+    kv_length: Optional[jax.Array] = None,  # scalar int32: valid cache length
+    window: Optional[int] = None,
+    scale: Optional[float] = None,  # score scale; None: 1/sqrt(D)
+) -> jax.Array:
+    """One query token against a cache, non-causal as ``attention_ref``.
+
+    The query heads of each KV head form one group of G = H / KV rows, so
+    both products read the cache as stored, once per KV head: no repeat
+    of K and V to every query head.  Operands keep their dtype, products
+    accumulate and the softmax runs in float32.
+    """
+    b, _, h, d = q.shape
+    skv, kvh = k.shape[1], k.shape[2]
+    with jax.named_scope("core"):
+        q4 = q.reshape(b, kvh, h // kvh, d)
+        s = jnp.einsum("bkgd,bskd->bkgs", q4, k, preferred_element_type=jnp.float32)
+        s = s / math.sqrt(d) if scale is None else s * scale
+        kpos = jnp.arange(skv)[None, None, None, :]
+        mask = kpos < (skv if kv_length is None else kv_length)
+        if window is not None:
+            mask &= kpos > q_positions[:, :, None, None] - window
+        s = jnp.where(mask, s, NEG_INF)
+        p = jax.nn.softmax(s, axis=-1)
+        out = jnp.einsum("bkgs,bskd->bkgd", p.astype(v.dtype), v,
+                         preferred_element_type=jnp.float32)
+    return out.reshape(b, 1, h, d).astype(q.dtype)
 
 
 # ---------------------------------------------------------------------------
@@ -360,10 +395,9 @@ def attn_apply(
         k_all, v_all = new_cache["k"], new_cache["v"]
         kv_len = new_cache["length"]
         if s == 1:
-            out = attention_ref(
+            out = decode_attention(
                 q, k_all.astype(q.dtype), v_all.astype(q.dtype), qpos1d,
-                kv_length=kv_len, causal=False, window=cfg.sliding_window,
-                scale=cfg.scale,
+                kv_len, cfg.sliding_window, cfg.scale,
             )
         else:
             out = flash_xla(

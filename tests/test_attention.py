@@ -13,6 +13,7 @@ from repro.models.attention import (
     attention_ref,
     attn_apply,
     attn_defs,
+    decode_attention,
     flash_xla,
     init_cache,
     init_mla_cache,
@@ -64,6 +65,28 @@ def test_flash_kv_length_mask():
     got = flash_xla(q, k, v, pos, jnp.asarray(40), True, None, 16)
     want = attention_ref(q, k, v, pos, kv_length=jnp.asarray(40), causal=True)
     np.testing.assert_allclose(got, want, atol=2e-5, rtol=2e-4)
+
+
+@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 1e-5), (jnp.bfloat16, 2e-2)],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("length", [11, 16], ids=["partial", "full"])
+@pytest.mark.parametrize("window", [None, 4])
+@pytest.mark.parametrize("h,kv", [(4, 4), (4, 2), (8, 1)])
+def test_decode_attention_matches_ref(h, kv, window, length, dtype, tol):
+    """One query token on a 16-position cache, each KV head serving its
+    whole query group, equals the oracle over heads repeated to H."""
+    b, skv, d = 3, 16, 8
+    q = jax.random.normal(jax.random.key(0), (b, 1, h, d)).astype(dtype)
+    k = jax.random.normal(jax.random.key(1), (b, skv, kv, d)).astype(dtype)
+    v = jax.random.normal(jax.random.key(2), (b, skv, kv, d)).astype(dtype)
+    pos = jnp.full((b, 1), length - 1, jnp.int32)
+    kv_length = jnp.asarray(length, jnp.int32)
+    got = decode_attention(q, k, v, pos, kv_length, window)
+    want = attention_ref(q, k, v, pos, kv_length=kv_length, causal=False,
+                         window=window)
+    assert got.shape == q.shape and got.dtype == dtype
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(want, np.float32), atol=tol, rtol=tol)
 
 
 def test_gqa_cache_decode_matches_full():

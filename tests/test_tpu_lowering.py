@@ -112,3 +112,28 @@ def test_cost_parser_reads_tpu_compiled_model(one_chip):
     assert "convolution(" in tpu_text
     tpu = hlo_cost.analyze(tpu_text)
     assert abs(tpu.flops - cpu.flops) / cpu.flops < 0.05
+
+
+def test_gqa_decode_reads_cache_once_per_kv_head(one_chip):
+    """One GQA layer's one-token step on a (16, 768, 8, 64) bfloat16 cache
+    with 32 query heads: K and V are not repeated to the query heads, the
+    cache is not copied to float32, and both products are MXU
+    convolutions, not VPU multiply-reduces over the repeated cache."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.models.attention import AttnConfig, abstract_cache, attn_apply, attn_defs
+    from repro.models.params import abstract_params
+
+    cfg = AttnConfig(d_model=2048, n_heads=32, n_kv_heads=8, head_dim=64)
+    params = abstract_params(attn_defs(cfg), jnp.bfloat16)
+    cache = abstract_cache(16, 768, 8, 64)
+    x = jax.ShapeDtypeStruct((16, 1, 2048), jnp.bfloat16)
+    pos = jax.ShapeDtypeStruct((16, 1), jnp.int32)
+    args = [jax.tree.map(lambda a: _on(one_chip, [a])[0], t) for t in (params, x, pos, cache)]
+    fn = jax.jit(lambda p, x, pos, c: attn_apply(p, x, pos, cfg, c))
+    text = fn.lower(*args).compile().as_text()
+    lines = text.splitlines()
+    assert len([ln for ln in lines if "convolution(" in ln and "/core/" in ln]) == 2
+    assert "f32[16,768,8,4,64]" not in text and "f32[16,768,8,64]" not in text
+    assert not [ln for ln in lines if "multiply_reduce" in ln and "f32[16,768,32]" in ln]
